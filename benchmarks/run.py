@@ -20,6 +20,8 @@ def main() -> None:
                     help="comma-separated subset of " + ",".join(ALL))
     args = ap.parse_args()
     wanted = args.only.split(",") if args.only else list(ALL)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     print("name,us_per_call,derived")
     t_start = time.time()

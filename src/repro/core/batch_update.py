@@ -52,6 +52,8 @@ scan bit-for-bit for parity testing):
 from __future__ import annotations
 
 import dataclasses
+import os
+from concurrent.futures import ThreadPoolExecutor
 from functools import partial
 
 import jax
@@ -181,8 +183,14 @@ def compile_tape(ops, labels, X, *, built: int, min_wave: int = MIN_WAVE,
 @jax.jit
 def _apply_deletes_jit(index: HNSWIndex, del_labels: jax.Array) -> HNSWIndex:
     """Vectorized markDelete: every allocated slot whose label is in
-    ``del_labels`` is flagged at once (padding label -1 never matches)."""
-    hit = jnp.any(index.labels[None, :] == del_labels[:, None], axis=0)
+    ``del_labels`` is flagged at once (padding label -1 never matches).
+
+    Membership is a binary search of each slot's label in the sorted
+    delete list, so memory stays O(N + D) rather than a [D, N] compare."""
+    srt = jnp.sort(del_labels)
+    pos = jnp.clip(jnp.searchsorted(srt, index.labels), 0,
+                   srt.shape[0] - 1)
+    hit = (srt[pos] == index.labels) & (index.labels >= 0)
     hit &= index.levels >= 0
     return dataclasses.replace(index, deleted=index.deleted | hit)
 
@@ -676,6 +684,7 @@ def apply_plan(params: HNSWParams, index: HNSWIndex, plan: WavePlan,
         waves[0] = (ops0[1:], labels0[1:], X0[1:])
         allocated = 1
     N = index.capacity
+    calls = []
     for ops_w, labels_w, X_w in waves:
         if not len(ops_w):
             continue
@@ -689,13 +698,44 @@ def apply_plan(params: HNSWParams, index: HNSWIndex, plan: WavePlan,
         # and the wave loop never blocks on a per-wave device sync
         may_reuse = bool(np.any(ops_w == OP_REPLACE)) \
             or len(ops_w) > N - allocated
-        index = _apply_wave_jit(
-            params, index, jnp.asarray(ops_p),
-            jnp.asarray(_pad_pow2(labels_w, -1)),
-            jnp.asarray(_pad_pow2(X_w, 0.0)),
-            variant, rotate_slots, may_reuse, tier)
+        calls.append(((jnp.asarray(ops_p),
+                       jnp.asarray(_pad_pow2(labels_w, -1)),
+                       jnp.asarray(_pad_pow2(X_w, 0.0))),
+                      (variant, rotate_slots, may_reuse, tier)))
         allocated = min(N, allocated + len(ops_w))
+    for program, (args, _) in zip(_wave_programs(params, index, calls),
+                                  calls):
+        index = program(index, *args)
     return index
+
+
+#: compiled wave programs, keyed by :func:`_wave_programs`
+_WAVE_PROGRAMS: dict = {}
+
+
+def _wave_programs(params: HNSWParams, index: HNSWIndex, calls) -> list:
+    """The compiled :func:`_apply_wave_jit` program of each wave call.
+
+    The programs a plan still lacks are lowered one after another and then
+    compiled side by side: XLA compiles outside the GIL, and a cold build
+    needs about a dozen wave programs of 10-20 s each on a TPU.
+    """
+    def key(args, static):
+        leaves = jax.tree.leaves((index, args))
+        return (params, static, tuple((a.shape, a.dtype, a.weak_type,
+                                       a.sharding) for a in leaves))
+
+    keys = [key(args, static) for args, static in calls]
+    lowered = {}
+    for k, (args, static) in zip(keys, calls):
+        if k not in _WAVE_PROGRAMS and k not in lowered:
+            lowered[k] = _apply_wave_jit.lower(params, index, *args, *static)
+    if lowered:
+        workers = min(len(lowered), os.cpu_count() or 1, 8)
+        with ThreadPoolExecutor(workers) as pool:
+            compiled = pool.map(lambda low: low.compile(), lowered.values())
+            _WAVE_PROGRAMS.update(zip(lowered, compiled))
+    return [_WAVE_PROGRAMS[k] for k in keys]
 
 
 def apply_update_batch_wave(params: HNSWParams, index: HNSWIndex, ops,

@@ -37,12 +37,13 @@ def dedup_ids(ids: jax.Array, dists: jax.Array) -> tuple[jax.Array, jax.Array]:
     Keeps the first occurrence in id-sorted order; duplicates become
     ``(-1, INF)``. Invalid (-1) entries stay invalid.
     """
-    order = jnp.argsort(ids)
-    s = ids[order]
+    # sorts carry their payload instead of argsort + gather / scatter,
+    # which run element by element when vmapped on TPU
+    s, perm = jax.lax.sort((ids, jnp.arange(ids.shape[0])), num_keys=1,
+                           is_stable=True)
     dup_sorted = jnp.concatenate([jnp.array([False]), (s[1:] == s[:-1]) & (s[1:] >= 0)])
     # unsort the dup mask back to original positions
-    inv = jnp.zeros_like(order).at[order].set(jnp.arange(order.shape[0]))
-    dup = dup_sorted[inv]
+    _, dup = jax.lax.sort((perm, dup_sorted), num_keys=1)
     ids = jnp.where(dup, INVALID, ids)
     dists = jnp.where(dup, INF, dists)
     return ids, dists

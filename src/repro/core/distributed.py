@@ -16,8 +16,7 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 from .common import INF, INVALID
 from .index import HNSWIndex, HNSWParams, empty_index
@@ -55,12 +54,26 @@ def build_sharded(params: HNSWParams, vectors, labels=None, *, nshards: int,
     return jax.tree.map(lambda *xs: jnp.stack(xs), *stacked)
 
 
+def _auto_axes(mesh: Mesh) -> Mesh:
+    """``mesh`` with every axis typed ``Auto``.
+
+    ``jax.make_mesh`` types its axes ``Explicit`` by default, which puts
+    the sharding into each array's type: the shard-local conds
+    of :func:`sharded_update` and plain indexing of a stacked index along
+    the shard axis then fail to resolve. Each shard here is an
+    independent sub-index, so nothing needs sharding in types.
+    """
+    return Mesh(mesh.devices, mesh.axis_names,
+                axis_types=(AxisType.Auto,) * len(mesh.axis_names))
+
+
 def shard_index(stacked: HNSWIndex, mesh: Mesh, axis: str) -> HNSWIndex:
     """Place a stacked index so its leading (shard) dim maps to ``axis``."""
-    sh = NamedSharding(mesh, P(axis))
+    sh = NamedSharding(_auto_axes(mesh), P(axis))
     return jax.tree.map(lambda x: jax.device_put(x, sh), stacked)
 
 
+@partial(jax.jit, static_argnames=("params", "k", "mesh", "axis", "ef"))
 def sharded_batch_knn(params: HNSWParams, stacked: HNSWIndex, Q: jax.Array,
                       k: int, mesh: Mesh, axis: str = "data",
                       ef: int | None = None):
@@ -90,11 +103,13 @@ def sharded_batch_knn(params: HNSWParams, stacked: HNSWIndex, Q: jax.Array,
         return top_l, top
 
     specs = jax.tree.map(lambda _: P(axis), stacked)
-    fn = shard_map(local, mesh=mesh, in_specs=(specs, P()),
-                   out_specs=(P(), P()), check_rep=False)
+    fn = jax.shard_map(local, mesh=_auto_axes(mesh), in_specs=(specs, P()),
+                       out_specs=(P(), P()), check_vma=False)
     return fn(stacked, Q)
 
 
+@partial(jax.jit, static_argnames=("params", "mesh", "axis", "variant",
+                                   "fresh_insert"))
 def sharded_update(params: HNSWParams, stacked: HNSWIndex,
                    del_label: jax.Array, x: jax.Array, new_label: jax.Array,
                    mesh: Mesh, axis: str = "data",
@@ -134,7 +149,7 @@ def sharded_update(params: HNSWParams, stacked: HNSWIndex,
         return jax.tree.map(lambda a: a[None], idx)
 
     specs = jax.tree.map(lambda _: P(axis), stacked)
-    fn = shard_map(local, mesh=mesh,
-                   in_specs=(specs, P(), P(), P()),
-                   out_specs=specs, check_rep=False)
+    fn = jax.shard_map(local, mesh=_auto_axes(mesh),
+                       in_specs=(specs, P(), P(), P()),
+                       out_specs=specs, check_vma=False)
     return fn(stacked, del_label, x, new_label)

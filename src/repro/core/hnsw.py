@@ -9,6 +9,7 @@ TPU adaptation notes:
 """
 from __future__ import annotations
 
+import dataclasses
 from functools import partial
 
 import jax
@@ -18,7 +19,7 @@ from .common import INF, INVALID
 from .metrics import dist_point
 from .index import HNSWIndex, HNSWParams, empty_index, sample_level
 from .prune import select_neighbors
-from .search import greedy_layer, search_layer
+from .search import _descend, search_layer
 
 
 def _pad_row(sel_ids: jax.Array, width: int) -> jax.Array:
@@ -66,41 +67,39 @@ def add_reverse_edges(params: HNSWParams, nbrs_layer: jax.Array,
     return nbrs_layer.at[safe].set(new_rows, mode="drop")
 
 
-def connect_at_layer(params: HNSWParams, nbrs: jax.Array, vectors: jax.Array,
-                     deleted: jax.Array, levels: jax.Array,
-                     index: HNSWIndex, x: jax.Array, pid: jax.Array,
-                     ep: jax.Array, layer: int, alpha: float,
-                     exclude_self: bool = True):
+def connect_at_layer(params: HNSWParams, nbrs: jax.Array, index: HNSWIndex,
+                     x: jax.Array, pid: jax.Array, ep: jax.Array, layer: int,
+                     alpha: float):
     """Search + select + wire one layer for point ``pid`` with vector ``x``.
 
-    Returns ``(nbrs, next_ep)``. ``index`` supplies the search view (its
-    ``neighbors`` must alias ``nbrs`` — the caller rebuilds the view).
+    ``nbrs`` is the ``[L, N, M0]`` adjacency being rewired; returns it with
+    the next entry point. The search walks ``nbrs`` itself (with
+    ``index``'s other fields), so no second adjacency stays live.
     """
     m_l = params.m_for_layer(layer)
-    ids, dists = search_layer(params, index, x, ep, layer, params.ef_construction)
-    ok = ids >= 0
-    if exclude_self:
-        ok &= ids != pid
+    view = dataclasses.replace(index, neighbors=nbrs)
+    ids, dists = search_layer(params, view, x, ep, layer,
+                              params.ef_construction)
+    ok = (ids >= 0) & (ids != pid)
     # prefer live candidates; when EVERY candidate is mark-deleted, link
     # through the deleted ones anyway (hnswlib semantics) — otherwise the
     # new point comes up with zero edges and is unreachable from the entry
-    alive = ok & ~deleted[jnp.clip(ids, 0)]
+    alive = ok & ~index.deleted[jnp.clip(ids, 0)]
     ok = jnp.where(jnp.any(alive), alive, ok)
     dists = jnp.where(ok, dists, INF)
     ids = jnp.where(ok, ids, INVALID)
 
-    cand_vecs = vectors[jnp.clip(ids, 0)]
+    cand_vecs = index.vectors[jnp.clip(ids, 0)]
     sel, _ = select_neighbors(x, ids, cand_vecs, dists, m_l, alpha,
                               params.space)
 
     layer_nbrs = nbrs[layer].at[pid].set(_pad_row(sel, params.M0))
-    layer_nbrs = add_reverse_edges(params, layer_nbrs, vectors, pid, sel,
-                                   layer, alpha)
-    nbrs = nbrs.at[layer].set(layer_nbrs)
+    layer_nbrs = add_reverse_edges(params, layer_nbrs, index.vectors, pid,
+                                   sel, layer, alpha)
 
     next_ep = jnp.where(ids[jnp.argmin(dists)] >= 0,
                         jnp.clip(ids[jnp.argmin(dists)], 0), ep)
-    return nbrs, next_ep
+    return nbrs.at[layer].set(layer_nbrs), next_ep
 
 
 def insert(params: HNSWParams, index: HNSWIndex, x: jax.Array,
@@ -130,26 +129,15 @@ def insert(params: HNSWParams, index: HNSWIndex, x: jax.Array,
 
     def nonempty_case(ix: HNSWIndex) -> HNSWIndex:
         nbrs = ix.neighbors
-        ep = jnp.clip(ix.entry, 0)
         # greedy descent through layers above the insertion level
-        for layer in range(params.num_layers - 1, 0, -1):
-            active = (layer <= ix.max_layer) & (layer > lvl)
-            ep = jax.lax.cond(
-                active,
-                lambda ep: greedy_layer(params, ix, x, ep, layer),
-                lambda ep: ep, ep)
+        ep = _descend(params, ix, x, lvl)
         # connect at layers min(lvl, max_layer)..0
         for layer in range(params.num_layers - 1, -1, -1):
             active = (layer <= lvl) & (layer <= ix.max_layer)
 
             def do(nbrs_ep, layer=layer):
-                nbrs, ep = nbrs_ep
-                view = HNSWIndex(ix.vectors, ix.labels, ix.levels, nbrs,
-                                 ix.deleted, ix.entry, ix.max_layer, ix.count,
-                                 ix.rng)
-                return connect_at_layer(params, nbrs, ix.vectors, ix.deleted,
-                                        ix.levels, view, x, pid, ep, layer,
-                                        params.alpha)
+                return connect_at_layer(params, nbrs_ep[0], ix, x, pid,
+                                        nbrs_ep[1], layer, params.alpha)
 
             nbrs, ep = jax.lax.cond(active, do, lambda t: t, (nbrs, ep))
         new_entry = jnp.where(lvl > ix.max_layer, pid, ix.entry).astype(jnp.int32)

@@ -15,8 +15,8 @@ the full blocking rebuild that used to be the only reclamation path:
     (``levels = -1``) so they become free capacity.
   * :func:`repair_unreachable` — batch re-link every unreachable live
     point (Definition-1 ∪ BFS) through the layer-inheriting reinsert path,
-    with a forced reverse edge as the connectivity backstop, driving the
-    Definition-1 count to zero.
+    then force an in-edge into every remaining orphan without taking
+    another point's last one, driving the Definition-1 count to zero.
   * :func:`index_health` — a jit-able :class:`IndexHealth` report (live /
     deleted / unreachable counts, in-degree histogram) that
     :class:`MaintenancePolicy` consumes to decide *when* the passes run —
@@ -44,8 +44,8 @@ from .common import INF, INVALID, pow2_at_least
 from .index import HNSWIndex, HNSWParams, empty_index
 from .metrics import dist_point
 from .prune import alpha_rng_select
-from .reach import bfs_unreachable, count_unreachable, indegree, \
-    indegree_unreachable
+from .reach import bfs_unreachable, count_unreachable, definition1, \
+    indegree, indegree_unreachable
 
 
 # ---------------------------------------------------------------------------
@@ -115,13 +115,14 @@ def index_health(index: HNSWIndex) -> IndexHealth:
     """
     alloc = index.levels >= 0
     live = alloc & ~index.deleted
-    u_def1, u_bfs = count_unreachable(index)
     deg = indegree(index)
+    u_def1 = jnp.sum(definition1(index, deg))
+    u_bfs = jnp.sum(bfs_unreachable(index))
     nbins = len(HIST_SPLITS) + 1
     b = jnp.searchsorted(jnp.asarray(HIST_SPLITS, jnp.int32), deg,
                          side="right")
-    hist = jnp.zeros((nbins,), jnp.int32).at[
-        jnp.where(live, b, nbins)].add(1, mode="drop")
+    hist = jnp.sum(live & (b == jnp.arange(nbins)[:, None]), axis=1,
+                   dtype=jnp.int32)
     return IndexHealth(
         capacity=jnp.int32(index.capacity),
         allocated=jnp.sum(alloc).astype(jnp.int32),
@@ -138,6 +139,12 @@ def index_health(index: HNSWIndex) -> IndexHealth:
 # batched delete consolidation (FreshDiskANN-style)
 # ---------------------------------------------------------------------------
 
+#: rows re-pruned per step of the consolidation sweep. Each step gathers
+#: the vectors of ``block * (M0 + M0**2)`` pool candidates, so the block
+#: (not the capacity) bounds the pass's temporary memory
+CONSOLIDATE_BLOCK = 1024
+
+
 def _consolidate_layer(params: HNSWParams, layer_nbrs: jax.Array,
                        vectors: jax.Array, live: jax.Array,
                        del_mask: jax.Array, layer: int) -> jax.Array:
@@ -149,18 +156,24 @@ def _consolidate_layer(params: HNSWParams, layer_nbrs: jax.Array,
     contraction before the (vmapped) alpha-RNG dominance sweep — the sweep
     is the expensive part, so the pre-reduction keeps its lane count
     bounded by the degree, not the pool square.
+
+    Only the affected rows are visited: their ids are compacted to the
+    front of one list and a ``fori_loop`` re-prunes them
+    :data:`CONSOLIDATE_BLOCK` rows at a time, so time scales with the
+    affected count and memory with the block. Every pool reads the
+    pre-pass adjacency, so the result does not depend on the block order.
     """
     N, M0 = layer_nbrs.shape
     m_l = params.m_for_layer(layer)
+    block = min(N, CONSOLIDATE_BLOCK)
 
     rc = jnp.clip(layer_nbrs, 0)
     edge_to_del = (layer_nbrs >= 0) & del_mask[rc]            # [N, M0]
     affected = live & jnp.any(edge_to_del, axis=1)            # [N]
-
-    # candidate pool per vertex: own row ∪ rows of its deleted neighbours
-    ext = jnp.where(edge_to_del[:, :, None], layer_nbrs[rc], INVALID)
-    pool = jnp.concatenate([layer_nbrs, ext.reshape(N, M0 * M0)], axis=1)
-    k_sel = min(pool.shape[1], 3 * M0)
+    n_blocks = (jnp.sum(affected) + block - 1) // block
+    rows_all = jnp.nonzero(affected, size=N + (-N) % block,
+                           fill_value=N)[0].astype(jnp.int32)
+    k_sel = min(M0 + M0 * M0, 3 * M0)
 
     def repair_one(v, vpool):
         pc = jnp.clip(vpool, 0)
@@ -177,8 +190,18 @@ def _consolidate_layer(params: HNSWParams, layer_nbrs: jax.Array,
         row = jnp.full((M0,), INVALID, jnp.int32).at[:m_l].set(sel[:m_l])
         return row
 
-    new_rows = jax.vmap(repair_one)(jnp.arange(N, dtype=jnp.int32), pool)
-    return jnp.where(affected[:, None], new_rows, layer_nbrs)
+    def repair_block(b, out):
+        rows = jax.lax.dynamic_slice(rows_all, (b * block,), (block,))
+        vc = jnp.clip(rows, 0, N - 1)
+        own = layer_nbrs[vc]                                  # [B, M0]
+        # candidate pool: own row ∪ rows of its deleted neighbours
+        ext = jnp.where(edge_to_del[vc][:, :, None],
+                        layer_nbrs[jnp.clip(own, 0)], INVALID)
+        pool = jnp.concatenate([own, ext.reshape(block, M0 * M0)], axis=1)
+        new_rows = jax.vmap(repair_one)(vc, pool)
+        return out.at[rows].set(new_rows, mode="drop")
+
+    return jax.lax.fori_loop(0, n_blocks, repair_block, layer_nbrs)
 
 
 def _consolidate(params: HNSWParams, index: HNSWIndex,
@@ -240,38 +263,48 @@ def consolidate_deletes(params: HNSWParams, index: HNSWIndex) -> HNSWIndex:
 # unreachable-point repair
 # ---------------------------------------------------------------------------
 
-def _ensure_in_edge(params: HNSWParams, index: HNSWIndex,
-                    pid: jax.Array) -> HNSWIndex:
-    """Connectivity backstop: guarantee ``pid`` keeps >= 1 in-edge.
+def _force_in_edges(params: HNSWParams, index: HNSWIndex) -> HNSWIndex:
+    """Connectivity backstop: give every Definition-1 orphan an in-edge.
 
-    The reinsert's reverse-edge pass (`add_reverse_edges`) may prune
-    ``pid`` straight back out of every full neighbour row, leaving it
-    Definition-1 unreachable again. When none of ``pid``'s out-neighbours
-    points back, force the nearest layer-0 out-neighbour to link ``pid``
-    (into a free slot if it has one, else evicting its farthest edge) —
-    the same keep-connected override hnswlib applies.
+    Re-linking point A can cost point B its last in-edge (A's reverse
+    edges re-prune full rows, and A's own row is rewritten), so a repair
+    pass alone can leave orphans behind pass after pass. Here each orphan
+    is linked from the nearest of its layer-0 out-neighbours whose row has
+    a free slot or holds a point with another in-edge to spare (the
+    farthest such point is evicted) — the keep-connected override hnswlib
+    applies, with in-degrees kept up to date so that no link taken here
+    orphans anyone.
     """
     L, N, M0 = index.neighbors.shape
-    out = index.neighbors[:, pid, :]                         # [L, M0]
-    oc = jnp.clip(out, 0)
-    rows_of_out = index.neighbors[jnp.arange(L)[:, None, None], oc[:, :, None],
-                                  jnp.arange(M0)[None, None, :]]  # [L, M0, M0]
-    has_in = jnp.any((rows_of_out == pid) & (out[:, :, None] >= 0))
+    deg = indegree(index)
+    mask = definition1(index, deg)
+    order = jnp.argsort(jnp.where(mask, jnp.arange(N), N))   # orphans first
 
-    e = index.neighbors[0, pid, 0]            # nearest layer-0 out-neighbour
+    def body(i, carry):
+        nbrs, deg = carry
+        pid = order[i]
+        owners = nbrs[0, pid]                                 # [M0]
+        oc = jnp.clip(owners, 0)
+        rows = nbrs[0, oc]                                    # [M0, M0]
+        rc = jnp.clip(rows, 0)
+        spare = (rows >= 0) & (rows != pid) & (deg[rc] >= 2)
+        d = jax.vmap(lambda o, r: dist_point(params.space, index.vectors[o],
+                                             index.vectors[r]))(oc, rc)
+        score = jnp.where(rows < 0, INF, jnp.where(spare, d, -INF))
+        usable = ((owners >= 0) & (owners != pid) & (index.levels[oc] >= 0)
+                  & jnp.any(score > -INF, axis=1))
+        j = jnp.argmax(usable)                 # nearest usable owner
+        k = jnp.argmax(score[j])               # free slot, else farthest
+        ok = usable[j] & (deg[pid] == 0)
+        victim = rows[j, k]
+        nbrs = nbrs.at[0, oc[j], k].set(jnp.where(ok, pid, victim))
+        deg = deg.at[pid].add(ok.astype(jnp.int32))
+        deg = deg.at[jnp.clip(victim, 0)].add(
+            -(ok & (victim >= 0)).astype(jnp.int32))
+        return nbrs, deg
 
-    def force(nbrs):
-        ec = jnp.clip(e, 0)
-        erow = nbrs[0, ec]
-        free = erow < 0
-        ed = jnp.where(free, -INF,
-                       dist_point(params.space, index.vectors[ec],
-                                  index.vectors[jnp.clip(erow, 0)]))
-        pos = jnp.where(jnp.any(free), jnp.argmax(free), jnp.argmax(ed))
-        return nbrs.at[0, ec, pos].set(pid)
-
-    nbrs = jax.lax.cond((e >= 0) & ~has_in, force, lambda n: n,
-                        index.neighbors)
+    nbrs, _ = jax.lax.fori_loop(0, jnp.sum(mask, dtype=jnp.int32), body,
+                                (index.neighbors, deg))
     return dataclasses.replace(index, neighbors=nbrs)
 
 
@@ -284,14 +317,15 @@ def repair_unreachable(params: HNSWParams, index: HNSWIndex) -> HNSWIndex:
     unreachability, then re-links each point through the layer-inheriting
     reinsert path (paper Algorithm 3: greedy descent above its level, beam
     search + alpha-RNG select + reverse edges at its levels), followed by
-    the :func:`_ensure_in_edge` backstop. One compiled program; the loop
-    bound is the (traced) unreachable count, so a healthy index pays only
-    the detection sweep.
+    the :func:`_force_in_edges` backstop for the Definition-1 orphans the
+    re-links left or made. One compiled program; the loop bounds are the
+    (traced) unreachable counts, so a healthy index pays only the
+    detection sweeps.
 
-    Repairing point A can, rarely, evict point B's last in-edge — callers
-    that need a hard Definition-1 == 0 guarantee loop this pass (see
-    :func:`run_maintenance` / ``VectorIndex.repair_unreachable``, which
-    re-check and converge in practice within a pass or two).
+    The backstop leaves a Definition-1 orphan only when none of its
+    layer-0 out-neighbours can take it without orphaning another point;
+    :func:`run_maintenance` / ``VectorIndex.repair_unreachable`` re-check
+    and run further passes for that case.
     """
     # local import: update.py imports nothing from this module, so the
     # dependency stays one-directional at runtime (both live in core)
@@ -304,10 +338,9 @@ def repair_unreachable(params: HNSWParams, index: HNSWIndex) -> HNSWIndex:
 
     def body(i, ix):
         pid = order[i]
-        ix = _update_reinsert(params, ix, ix.vectors[pid], pid, params.alpha)
-        return _ensure_in_edge(params, ix, pid)
+        return _update_reinsert(params, ix, ix.vectors[pid], pid, params.alpha)
 
-    return jax.lax.fori_loop(0, n_u, body, index)
+    return _force_in_edges(params, jax.lax.fori_loop(0, n_u, body, index))
 
 
 # ---------------------------------------------------------------------------
@@ -405,13 +438,13 @@ def run_maintenance(params: HNSWParams, index: HNSWIndex,
         ran = True
     if ran or policy.should_repair(h):
         for _ in range(policy.repair_passes):
-            def1, _bfs = count_unreachable(index)
-            report["unreachable_def1"] = int(def1)
-            if int(def1) <= policy.unreachable:
+            def1 = int(jnp.sum(indegree_unreachable(index)))
+            report["unreachable_def1"] = def1
+            if def1 <= policy.unreachable:
                 break
             index = repair_unreachable(params, index)
             report["repair_passes"] += 1
         else:
-            def1, _bfs = count_unreachable(index)
-            report["unreachable_def1"] = int(def1)
+            report["unreachable_def1"] = int(
+                jnp.sum(indegree_unreachable(index)))
     return index, report

@@ -40,10 +40,10 @@ def select_neighbors(
     """
     C, d = cand_vecs.shape
     cand_ids, cand_dists = dedup_ids(cand_ids, cand_dists)
-    order = jnp.argsort(cand_dists)
-    ids = cand_ids[order]
-    dq = cand_dists[order]
-    vecs = cand_vecs[order]
+    # sort ids with their keys; vectors are read one row per step below
+    # instead of gathering the whole [C, d] block into sorted order
+    dq, ids, order = jax.lax.sort(
+        (cand_dists, cand_ids, jnp.arange(C)), num_keys=1, is_stable=True)
 
     def cond(state):
         i, selected, sel_vecs, count = state
@@ -52,27 +52,26 @@ def select_neighbors(
 
     def body(state):
         i, selected, sel_vecs, count = state
-        v = vecs[i]
+        v = cand_vecs[order[i]]
         dd = dist_point(space, v, sel_vecs)                   # d(r, c_i)
         active = jnp.arange(m_out) < count
         dom = jnp.any(active & (alpha * dd <= dq[i]))
         keep = (~dom) & (dq[i] < INF)
-        sel_vecs = jax.lax.cond(
-            keep,
-            lambda sv: jax.lax.dynamic_update_slice(sv, v[None], (count, 0)),
-            lambda sv: sv, sel_vecs)
-        selected = selected.at[i].set(keep)
+        # masked writes, not a cond + dynamic_update_slice / scatter: under
+        # vmap those become a serial loop over the lanes
+        sel_vecs = jnp.where(((jnp.arange(m_out) == count) & keep)[:, None],
+                             v[None, :], sel_vecs)
+        selected = selected | ((jnp.arange(C) == i) & keep)
         return i + 1, selected, sel_vecs, count + keep.astype(jnp.int32)
 
     init = (jnp.int32(0), jnp.zeros((C,), jnp.bool_),
-            jnp.zeros((m_out, d), vecs.dtype), jnp.int32(0))
+            jnp.zeros((m_out, d), cand_vecs.dtype), jnp.int32(0))
     _, selected, _, _ = jax.lax.while_loop(cond, body, init)
 
-    key = jnp.where(selected, dq, INF)
-    out_order = jnp.argsort(key)
-    out_ids = jnp.where(key[out_order] < INF, ids[out_order], INVALID)[:m_out]
-    out_d = key[out_order][:m_out]
-    return out_ids, out_d
+    key, ids = jax.lax.sort((jnp.where(selected, dq, INF), ids), num_keys=1,
+                            is_stable=True)
+    out_ids = jnp.where(key < INF, ids, INVALID)[:m_out]
+    return out_ids, key[:m_out]
 
 
 def alpha_rng_select(

@@ -31,8 +31,16 @@ from .metrics import dist_point
 
 
 def greedy_layer(params: HNSWParams, index: HNSWIndex, q: jax.Array,
-                 ep: jax.Array, layer: int) -> jax.Array:
-    """ef=1 greedy descent within one layer; returns the improved entry point."""
+                 ep: jax.Array, layer: int,
+                 active: jax.Array | bool = True) -> jax.Array:
+    """ef=1 greedy descent within one layer; returns the improved entry point.
+
+    ``active=False`` makes the walk take no step (``ep`` comes back
+    clipped). The flag seeds the loop's own predicate instead of wrapping
+    the call in a ``lax.cond``: under ``vmap`` a cond with a batched
+    predicate becomes a select over both branches' operands, which
+    broadcasts the whole index to every lane.
+    """
     nbrs_l = index.neighbors[layer]
 
     def cond(state):
@@ -53,7 +61,8 @@ def greedy_layer(params: HNSWParams, index: HNSWIndex, q: jax.Array,
         return cur, cur_d, improved
 
     d0 = dist_point(params.space, q, index.vectors[jnp.clip(ep, 0)])
-    cur, _, _ = jax.lax.while_loop(cond, body, (jnp.clip(ep, 0), d0, jnp.bool_(True)))
+    cur, _, _ = jax.lax.while_loop(
+        cond, body, (jnp.clip(ep, 0), d0, jnp.asarray(active, jnp.bool_)))
     return cur
 
 
@@ -67,7 +76,6 @@ def search_layer(params: HNSWParams, index: HNSWIndex, q: jax.Array,
     deleted ids out of returned results. With ``allow`` (bool[N] slot mask),
     traversal is unchanged but the returned beam contains only allowed slots.
     """
-    N = index.capacity
     M0 = params.M0
     steps_cap = max_steps if max_steps is not None else params.steps_for(ef)
     nbrs_l = index.neighbors[layer]
@@ -78,7 +86,6 @@ def search_layer(params: HNSWParams, index: HNSWIndex, q: jax.Array,
     dists = jnp.full((ef,), INF).at[0].set(d0)
     ids = jnp.full((ef,), INVALID, jnp.int32).at[0].set(ep)
     expanded = jnp.zeros((ef,), jnp.bool_)
-    visited = jnp.zeros((N,), jnp.bool_).at[ep].set(True)
     if filtered:
         ep_ok = allow[ep]
         res_d = jnp.full((ef,), INF).at[0].set(jnp.where(ep_ok, d0, INF))
@@ -91,22 +98,25 @@ def search_layer(params: HNSWParams, index: HNSWIndex, q: jax.Array,
         return jnp.where(expanded | (ids < 0), INF, dists)
 
     def cond(state):
-        dists, ids, expanded, visited, steps = state[:5]
+        dists, ids, expanded, steps = state[:4]
         return (jnp.min(frontier(dists, ids, expanded)) < INF) & (steps < steps_cap)
 
     def body(state):
-        dists, ids, expanded, visited, steps = state[:5]
+        dists, ids, expanded, steps = state[:4]
         f = frontier(dists, ids, expanded)
         i = jnp.argmin(f)
         cur = jnp.clip(ids[i], 0)
-        expanded = expanded.at[i].set(True)
+        expanded = expanded | (jnp.arange(ef) == i)
 
         nbrs = nbrs_l[cur]                            # [M0]
         valid = nbrs >= 0
         nc = jnp.clip(nbrs, 0)
-        fresh = valid & ~visited[nc]
-        # mark visited (drop invalid via OOB index)
-        visited = visited.at[jnp.where(valid, nc, N)].set(True, mode="drop")
+        # a neighbour already in the beam is not new. One seen and dropped
+        # before scores no better now, and the beam's worst distance only
+        # falls, so the merge drops it again: no visited set over all N
+        # slots is needed (under vmap the loop would carry one per lane and
+        # select over it on every step)
+        fresh = valid & ~jnp.any(nc[:, None] == ids[None, :], axis=1)
 
         nv = index.vectors[nc]                        # [M0, d]
         nd = jnp.where(fresh, dist_point(params.space, q, nv), INF)
@@ -114,24 +124,27 @@ def search_layer(params: HNSWParams, index: HNSWIndex, q: jax.Array,
         all_d = jnp.concatenate([dists, nd])
         all_i = jnp.concatenate([ids, jnp.where(fresh, nc, INVALID)])
         all_e = jnp.concatenate([expanded, jnp.zeros((M0,), jnp.bool_)])
-        order = jnp.argsort(all_d)
-        out = (all_d[order][:ef], all_i[order][:ef], all_e[order][:ef],
-               visited, steps + 1)
+        # one stable sort carries ids and flags with the keys: an argsort
+        # plus gathers becomes a serial per-element gather under vmap
+        all_d, all_i, all_e = jax.lax.sort((all_d, all_i, all_e),
+                                           num_keys=1, is_stable=True)
+        out = (all_d[:ef], all_i[:ef], all_e[:ef], steps + 1)
         if filtered:
-            res_d, res_i = state[5:]
-            a_ok = fresh & allow[nc]
+            res_d, res_i = state[4:]
+            a_ok = (fresh & allow[nc]
+                    & ~jnp.any(nc[:, None] == res_i[None, :], axis=1))
             rd = jnp.concatenate([res_d, jnp.where(a_ok, nd, INF)])
             ri = jnp.concatenate([res_i, jnp.where(a_ok, nc, INVALID)])
-            r_order = jnp.argsort(rd)
-            out = out + (rd[r_order][:ef], ri[r_order][:ef])
+            rd, ri = jax.lax.sort((rd, ri), num_keys=1, is_stable=True)
+            out = out + (rd[:ef], ri[:ef])
         return out
 
-    init = (dists, ids, expanded, visited, jnp.int32(0))
+    init = (dists, ids, expanded, jnp.int32(0))
     if filtered:
         init = init + (res_d, res_i)
     final = jax.lax.while_loop(cond, body, init)
     if filtered:
-        return final[6], final[5]
+        return final[5], final[4]
     return final[1], final[0]
 
 
@@ -141,12 +154,7 @@ def _descend(params: HNSWParams, index: HNSWIndex, q: jax.Array,
     ep = jnp.clip(index.entry, 0)
     for layer in range(params.num_layers - 1, 0, -1):
         active = (layer <= index.max_layer) & (layer > down_to_layer)
-        ep = jax.lax.cond(
-            active,
-            lambda ep: greedy_layer(params, index, q, ep, layer),
-            lambda ep: ep,
-            ep,
-        )
+        ep = greedy_layer(params, index, q, ep, layer, active)
     return ep
 
 

@@ -26,10 +26,10 @@ import jax.numpy as jnp
 
 from .common import INF, INVALID, dedup_ids
 from .index import HNSWIndex, HNSWParams
-from .hnsw import _pad_row, add_reverse_edges, insert
+from .hnsw import _pad_row, add_reverse_edges, connect_at_layer, insert
 from .metrics import dist_point
 from .prune import alpha_rng_select, select_neighbors
-from .search import greedy_layer, search_layer
+from .search import _descend, search_layer
 from .strategies import (BUILTIN_STRATEGIES, UpdateStrategy,  # noqa: F401
                          get_executor, get_strategy, list_strategies,
                          register_executor, register_strategy)
@@ -209,40 +209,14 @@ def _update_reinsert(params: HNSWParams, index: HNSWIndex, x: jax.Array,
     """Re-link slot ``pid`` (already holding vector x) at its inherited level."""
     lvl = index.levels[pid]
     nbrs = index.neighbors
-    ep = jnp.clip(index.entry, 0)
-    for layer in range(params.num_layers - 1, 0, -1):
-        active = (layer <= index.max_layer) & (layer > lvl)
-        ep = jax.lax.cond(
-            active,
-            lambda ep: greedy_layer(params, index, x, ep, layer),
-            lambda ep: ep, ep)
+    ep = _descend(params, index, x, lvl)
 
     for layer in range(params.num_layers - 1, -1, -1):
         active = layer <= lvl
 
         def do(nbrs_ep, layer=layer):
-            nbrs, ep = nbrs_ep
-            view = HNSWIndex(index.vectors, index.labels, index.levels, nbrs,
-                             index.deleted, index.entry, index.max_layer,
-                             index.count, index.rng)
-            m_l = params.m_for_layer(layer)
-            ids, dists = search_layer(params, view, x, ep, layer,
-                                      params.ef_construction)
-            ok = (ids >= 0) & (ids != pid)
-            # same all-deleted fallback as construction (see connect_at_layer)
-            alive = ok & ~index.deleted[jnp.clip(ids, 0)]
-            ok = jnp.where(jnp.any(alive), alive, ok)
-            dists = jnp.where(ok, dists, INF)
-            ids = jnp.where(ok, ids, INVALID)
-            cand_vecs = index.vectors[jnp.clip(ids, 0)]
-            sel, _ = select_neighbors(x, ids, cand_vecs, dists, m_l,
-                                      insert_alpha, params.space)
-            layer_nbrs = nbrs[layer].at[pid].set(_pad_row(sel, params.M0))
-            layer_nbrs = add_reverse_edges(params, layer_nbrs, index.vectors,
-                                           pid, sel, layer, insert_alpha)
-            next_ep = jnp.where(ids[jnp.argmin(dists)] >= 0,
-                                jnp.clip(ids[jnp.argmin(dists)], 0), ep)
-            return nbrs.at[layer].set(layer_nbrs), next_ep
+            return connect_at_layer(params, nbrs_ep[0], index, x, pid,
+                                    nbrs_ep[1], layer, insert_alpha)
 
         nbrs, ep = jax.lax.cond(active, do, lambda t: t, (nbrs, ep))
 

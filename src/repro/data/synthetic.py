@@ -28,7 +28,12 @@ def brute_force_knn(X: np.ndarray, Q: np.ndarray, k: int) -> np.ndarray:
     for i in range(0, Q.shape[0], 256):
         q = Q[i:i + 256]
         d = xn[None, :] - 2 * q @ X.T
-        out[i:i + 256] = np.argsort(d, axis=1)[:, :k]
+        if k < X.shape[0]:      # select the k smallest, then sort only those
+            top = np.argpartition(d, k - 1, axis=1)[:, :k]
+            order = np.argsort(np.take_along_axis(d, top, 1), axis=1)
+            out[i:i + 256] = np.take_along_axis(top, order, 1)
+        else:
+            out[i:i + 256] = np.argsort(d, axis=1)[:, :k]
     return out
 
 

@@ -26,9 +26,10 @@ def topk_dist(Q: jax.Array, Y: jax.Array, k: int, *, metric: str = "l2",
     exact scan tier skips deleted / filtered-out slots. Rows with fewer
     than k eligible candidates pad with ``(inf, -1)``.
 
-    Padding contract: pads Q/Y/mask freely to block multiples; padded
-    candidates are masked inside the kernel via the real-N bound, padded
-    query rows are sliced off the output. ``interpret=None`` auto-selects
+    Padding contract: pads Q/Y/mask freely to block multiples (Q to whole
+    ``bq``-row blocks, whatever the batch size); padded candidates are
+    masked inside the kernel via the real-N bound, padded query rows are
+    sliced off the output. ``interpret=None`` auto-selects
     the Pallas interpreter off-TPU; ``use_ref=True`` routes to the jnp
     oracle (identical semantics, XLA-fused instead of hand-tiled).
     """
@@ -41,12 +42,14 @@ def topk_dist(Q: jax.Array, Y: jax.Array, k: int, *, metric: str = "l2",
     if nq == 0:                              # empty batch: nothing to scan
         return (jnp.zeros((0, k), jnp.float32),
                 jnp.full((0, k), -1, jnp.int32))
-    bq_ = min(bq, nq) if nq % min(bq, nq) == 0 else 1
+    # Q pads up to whole (bq, d) blocks: a block row count that is not a
+    # multiple of 8 does not tile the TPU's sublanes
+    bq_ = bq
     bn_ = min(bn, N)
     pad_q = (-nq) % bq_
     pad_n = (-N) % bn_
     Qp = jnp.pad(Q, ((0, pad_q), (0, 0)))
-    Yp = jnp.pad(Y, ((0, pad_n), (0, 0)))
+    Yp = jnp.pad(Y, ((0, pad_n), (0, 0))) if pad_n else Y
     if mask is None:
         mp = jnp.ones((1, N + pad_n), jnp.int32)
     else:

@@ -22,8 +22,9 @@ live candidate, and unfilled output slots keep the ``(inf, -1)`` sentinel.
 Grid/tiling: grid = (Q/bq, N/bn), candidate axis innermost so the output
 block (the running buffer) stays VMEM-resident across the sweep. Per step
 the kernel sees ``q[bq, d]``, ``y[bn, d]``, ``mask[1, bn]`` blocks. The
-top-k merge is k rounds of masked min-extraction over [bq, k+bn] — pure VPU
-elementwise/reduce ops (no gather, no sort), so it lowers cleanly to
+top-k merge is k rounds of masked min-extraction over the [bq, k] buffer
+and the [bq, bn] tile — pure VPU elementwise/reduce ops (no gather, sort,
+scan or lane concatenation), so it lowers cleanly to
 Mosaic. Padding contract: Q and N must divide their blocks exactly (the
 ``ops.topk_dist`` wrapper pads and passes ``n_real``; padded candidate
 columns are masked by the global-id bound). Interpret-mode fallback: pass
@@ -42,25 +43,38 @@ _INF = float("inf")
 _METRIC_FORMS = ("l2", "ip")
 
 
-def _merge_topk(vals, ids, k):
-    """k rounds of masked min-extraction. vals/ids: [bq, C] -> ([bq,k],[bq,k]).
+def _merge_topk(buf_v, buf_i, tile_v, tile_i, k):
+    """k rounds of masked min-extraction over the running buffer and a tile.
+
+    ``buf_v/buf_i`` [bq, k] and ``tile_v/tile_i`` [bq, bn] -> ([bq,k],[bq,k]).
+    Each round takes the row minimum and, among the entries that hit it,
+    the one with the smallest id: finite entries carry distinct real ids
+    and the buffer's ids all precede the tile's, so this is the same
+    lowest-column tie order as an argmin over the concatenated row. The
+    two halves are reduced separately and the output is assembled with
+    iota masks, so the kernel needs no lane-axis concatenation or scan.
 
     An extraction that only finds ``inf`` (fewer than k eligible candidates
     so far) emits the ``(inf, -1)`` sentinel — never a real id — so masked
     or already-extracted columns can't leak into unfilled output slots.
     """
-    out_v = []
-    out_i = []
-    for _ in range(k):
-        m = jnp.min(vals, axis=1)                                   # [bq]
-        hit = vals == m[:, None]
-        first = (jnp.cumsum(hit.astype(jnp.int32), axis=1) == 1) & hit
-        sel_id = jnp.sum(jnp.where(first, ids, 0), axis=1)
-        sel_id = jnp.where(jnp.isinf(m), -1, sel_id)
-        out_v.append(m)
-        out_i.append(sel_id)
-        vals = jnp.where(first, _INF, vals)
-    return jnp.stack(out_v, axis=1), jnp.stack(out_i, axis=1)
+    big = jnp.iinfo(jnp.int32).max
+    col = jax.lax.broadcasted_iota(jnp.int32, buf_v.shape, 1)
+    out_v = jnp.full(buf_v.shape, _INF, jnp.float32)
+    out_i = jnp.full(buf_i.shape, -1, jnp.int32)
+    for r in range(k):
+        m = jnp.minimum(jnp.min(buf_v, axis=1, keepdims=True),
+                        jnp.min(tile_v, axis=1, keepdims=True))     # [bq, 1]
+        sel = jnp.minimum(
+            jnp.min(jnp.where(buf_v == m, buf_i, big), axis=1, keepdims=True),
+            jnp.min(jnp.where(tile_v == m, tile_i, big), axis=1,
+                    keepdims=True))
+        finite = m < _INF
+        out_v = jnp.where(col == r, m, out_v)
+        out_i = jnp.where(col == r, jnp.where(finite, sel, -1), out_i)
+        buf_v = jnp.where(finite & (buf_i == sel), _INF, buf_v)
+        tile_v = jnp.where(finite & (tile_i == sel), _INF, tile_v)
+    return out_v, out_i
 
 
 def _topk_dist_kernel(q_ref, y_ref, m_ref, od_ref, oi_ref, *, k, bn, n_real,
@@ -74,8 +88,12 @@ def _topk_dist_kernel(q_ref, y_ref, m_ref, od_ref, oi_ref, *, k, bn, n_real,
 
     q = q_ref[...].astype(jnp.float32)                              # [bq, d]
     y = y_ref[...].astype(jnp.float32)                              # [bn, d]
+    # full f32 contraction: the TPU's default precision rounds f32 operands
+    # to bf16, and the exact tier is held to an f32 brute-force reference
+    # (how far a default-precision pass strays from it is not measured)
     qy = jax.lax.dot_general(
-        q, y, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+        q, y, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST)
     if metric == "l2":
         qq = jnp.sum(q * q, axis=1, keepdims=True)
         yy = jnp.sum(y * y, axis=1, keepdims=True)
@@ -87,9 +105,7 @@ def _topk_dist_kernel(q_ref, y_ref, m_ref, od_ref, oi_ref, *, k, bn, n_real,
     ok = (gid < n_real) & (m_ref[...] > 0)       # [1, bn] mask broadcasts
     d = jnp.where(ok, d, _INF)                   # padding + masked-out slots
 
-    vals = jnp.concatenate([od_ref[...], d], axis=1)                # [bq, k+bn]
-    ids = jnp.concatenate([oi_ref[...], gid], axis=1)
-    nv, ni = _merge_topk(vals, ids, k)
+    nv, ni = _merge_topk(od_ref[...], oi_ref[...], d, gid, k)
     od_ref[...] = nv
     oi_ref[...] = ni
 

@@ -25,6 +25,7 @@ import numpy as np
 
 from repro import api
 from repro.data import clustered_vectors, exact_knn
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main():
@@ -73,6 +74,7 @@ def main():
     ap.add_argument("--metrics-json", default="",
                     help="path to dump the metrics registry as JSON")
     args = ap.parse_args()
+    enable_compile_cache()
 
     rng = np.random.default_rng(0)
     X = clustered_vectors(args.n, args.dim, seed=0)

@@ -337,3 +337,31 @@ def test_interleaved_update_consolidate_never_loses_live_labels():
             assert got == live, (op, live - got, got - live)
 
     run()
+
+
+def test_backstop_keeps_every_last_in_edge():
+    """The orphan backstop links an orphan from its nearest out-neighbour
+    and evicts the farthest entry that has another in-edge, never a
+    point's last one: slot 4 is the farthest entry of owner 0's full row
+    but has no other in-edge, so slot 3 is evicted instead."""
+    from repro.core.index import HNSWIndex, HNSWParams
+    from repro.core.maintenance import _force_in_edges
+    from repro.core.reach import indegree_unreachable
+    params = HNSWParams(M=4, M0=4, num_layers=1)
+    rows = {0: [1, 2, 3, 4], 4: [5], 5: [1, 2, 3], 6: [0]}
+    nbrs = np.full((1, 8, 4), -1, np.int32)
+    for src, tgt in rows.items():
+        nbrs[0, src, :len(tgt)] = tgt
+    pos = np.array([0.0, 1.0, 2.0, 3.0, 10.0, 5.0, 0.5, 0.0], np.float32)
+    idx = HNSWIndex(
+        vectors=jnp.asarray(pos[:, None]),
+        labels=jnp.arange(8, dtype=jnp.int32),
+        levels=jnp.asarray([0] * 7 + [-1], jnp.int32),
+        neighbors=jnp.asarray(nbrs), deleted=jnp.zeros(8, bool),
+        entry=jnp.int32(0), max_layer=jnp.int32(0), count=jnp.int32(7),
+        rng=jnp.zeros(2, jnp.uint32))
+    assert np.nonzero(np.asarray(indegree_unreachable(idx)))[0].tolist() \
+        == [6]
+    out = _force_in_edges(params, idx)
+    assert np.asarray(out.neighbors)[0, 0].tolist() == [1, 2, 6, 4]
+    assert not np.asarray(indegree_unreachable(out)).any()

@@ -1,6 +1,8 @@
 """Unreachability detection vs numpy brute force + crafted graphs."""
-import numpy as np
+import jax
 import jax.numpy as jnp
+import numpy as np
+import pytest
 
 from repro.core import (HNSWParams, bfs_reachable, bfs_unreachable,
                         empty_index, indegree, indegree_unreachable)
@@ -58,3 +60,46 @@ def test_build_graph_fully_reachable(small_params, small_index):
     # fresh builds should have (near) zero unreachable points
     assert int(u_ind) <= 2
     assert int(u_bfs) <= 6
+
+
+def _numpy_reach(levels, nbrs, entry):
+    """Dense BFS fix-point per layer, top down (the reference)."""
+    L, N, _ = nbrs.shape
+    reached = np.zeros(N, bool)
+    if entry >= 0:
+        reached[entry] = True
+    for layer in range(L - 1, -1, -1):
+        while True:
+            t = nbrs[layer][reached].reshape(-1)
+            new = reached.copy()
+            new[t[t >= 0]] = True
+            if (new == reached).all():
+                break
+            reached = new
+    return reached
+
+
+@pytest.mark.parametrize("n,m0,rows", [(5, 2, 2), (300, 4, 16),
+                                       (5000, 8, 64)])
+def test_sweeps_match_dense_reference(monkeypatch, n, m0, rows):
+    """The blocked sweeps (queue BFS, compacted in-degree) give the dense
+    reference's answers on random graphs, with blocks smaller than the
+    frontier so the queue wraps over many steps."""
+    from repro.core import reach
+    monkeypatch.setattr(reach, "SWEEP_ROWS", rows)
+    rng = np.random.default_rng(n)
+    L = 3
+    levels = np.minimum(rng.geometric(0.5, n) - 1, L - 1).astype(np.int32)
+    levels[rng.random(n) < 0.2] = -1
+    nbrs = rng.integers(-1, n, (L, n, m0)).astype(np.int32)
+    nbrs[rng.random((L, n, m0)) < 0.5] = -1
+    entry = int(np.argmax(levels))
+    idx = _craft(HNSWParams(M=m0, M0=m0, num_layers=L), n, {}, entry, levels)
+    idx = idx.__class__(**{**idx.__dict__, "neighbors": jnp.asarray(nbrs)})
+    # fresh jits: SWEEP_ROWS is read at trace time
+    got = np.asarray(jax.jit(reach.bfs_reachable.__wrapped__)(idx))
+    assert (got == _numpy_reach(levels, nbrs, entry)).all()
+    src = (levels >= 0)[None, :, None] & (nbrs >= 0)
+    want = np.bincount(nbrs[src], minlength=n)
+    got = np.asarray(jax.jit(reach.indegree.__wrapped__)(idx))
+    assert (got == want).all()
