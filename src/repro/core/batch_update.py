@@ -60,7 +60,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .common import INF, INVALID, dedup_ids, pow2_at_least
+from .common import INF, INVALID, dedup_ids, pow2_at_least, span
 from .hnsw import _pad_row, insert_jit
 from .index import HNSWIndex, HNSWParams, empty_index, sample_levels
 from .metrics import dist_pairwise, dist_point
@@ -669,48 +669,54 @@ def apply_plan(params: HNSWParams, index: HNSWIndex, plan: WavePlan,
     wave through :func:`_apply_wave_jit` (each padded to its pow2 bucket so
     ragged tapes reuse a bounded set of compiled programs)."""
     get_strategy(variant)
-    if plan.num_deletes:
-        index = _apply_deletes_jit(
-            index, jnp.asarray(_pad_pow2(plan.del_labels, -1)))
-    waves = list(plan.waves)
-    allocated = int(index.count)    # ONE host sync; waves book-keep below
-    if waves and allocated == 0:
-        # empty-graph bootstrap: the first point inserts sequentially (it
-        # has nothing to search against), the rest ride the waves
-        ops0, labels0, X0 = waves[0]
-        p0 = first_free_slot(index) if rotate_slots else jnp.int32(0)
-        index = insert_jit(params, index, jnp.asarray(X0[0]),
-                           jnp.clip(p0, 0), jnp.int32(labels0[0]))
-        waves[0] = (ops0[1:], labels0[1:], X0[1:])
-        allocated = 1
-    N = index.capacity
-    calls = []
-    for ops_w, labels_w, X_w in waves:
-        if not len(ops_w):
-            continue
-        ops_p = _pad_pow2(ops_w, OP_NOP)
-        tier = "scan" if len(ops_p) * N <= SCAN_TIER_MAX_ELEMS else "beam"
-        # the repair sweep must also run when inserts can spill into
-        # mark-deleted slots (capacity pressure) — those reuse a slot with
-        # live in-edges exactly like a replace does. ``allocated`` is an
-        # upper bound maintained host-side (as if every write allocated),
-        # so the check can only over-trigger the sweep, never miss it —
-        # and the wave loop never blocks on a per-wave device sync
-        may_reuse = bool(np.any(ops_w == OP_REPLACE)) \
-            or len(ops_w) > N - allocated
-        calls.append(((jnp.asarray(ops_p),
-                       jnp.asarray(_pad_pow2(labels_w, -1)),
-                       jnp.asarray(_pad_pow2(X_w, 0.0))),
-                      (variant, rotate_slots, may_reuse, tier)))
-        allocated = min(N, allocated + len(ops_w))
-    for program, (args, _) in zip(_wave_programs(params, index, calls),
-                                  calls):
-        index = program(index, *args)
-    return index
+    with span("waves", waves=plan.num_waves):
+        if plan.num_deletes:
+            index = _apply_deletes_jit(
+                index, jnp.asarray(_pad_pow2(plan.del_labels, -1)))
+        waves = list(plan.waves)
+        allocated = int(index.count)    # ONE host sync; waves book-keep below
+        if waves and allocated == 0:
+            # empty-graph bootstrap: the first point inserts sequentially (it
+            # has nothing to search against), the rest ride the waves
+            ops0, labels0, X0 = waves[0]
+            p0 = first_free_slot(index) if rotate_slots else jnp.int32(0)
+            index = insert_jit(params, index, jnp.asarray(X0[0]),
+                               jnp.clip(p0, 0), jnp.int32(labels0[0]))
+            waves[0] = (ops0[1:], labels0[1:], X0[1:])
+            allocated = 1
+        N = index.capacity
+        calls = []
+        for ops_w, labels_w, X_w in waves:
+            if not len(ops_w):
+                continue
+            ops_p = _pad_pow2(ops_w, OP_NOP)
+            tier = "scan" if len(ops_p) * N <= SCAN_TIER_MAX_ELEMS else "beam"
+            # the repair sweep must also run when inserts can spill into
+            # mark-deleted slots (capacity pressure) — those reuse a slot with
+            # live in-edges exactly like a replace does. ``allocated`` is an
+            # upper bound maintained host-side (as if every write allocated),
+            # so the check can only over-trigger the sweep, never miss it —
+            # and the wave loop never blocks on a per-wave device sync
+            may_reuse = bool(np.any(ops_w == OP_REPLACE)) \
+                or len(ops_w) > N - allocated
+            calls.append(((jnp.asarray(ops_p),
+                           jnp.asarray(_pad_pow2(labels_w, -1)),
+                           jnp.asarray(_pad_pow2(X_w, 0.0))),
+                          (variant, rotate_slots, may_reuse, tier)))
+            allocated = min(N, allocated + len(ops_w))
+        for program, (args, _) in zip(_wave_programs(params, index, calls),
+                                      calls):
+            index = program(index, *args)
+        return index
 
 
 #: compiled wave programs, keyed by :func:`_wave_programs`
 _WAVE_PROGRAMS: dict = {}
+
+
+def compiled_wave_programs() -> int:
+    """How many wave programs this process has compiled."""
+    return len(_WAVE_PROGRAMS)
 
 
 def _wave_programs(params: HNSWParams, index: HNSWIndex, calls) -> list:
@@ -726,15 +732,16 @@ def _wave_programs(params: HNSWParams, index: HNSWIndex, calls) -> list:
                                        a.sharding) for a in leaves))
 
     keys = [key(args, static) for args, static in calls]
-    lowered = {}
-    for k, (args, static) in zip(keys, calls):
-        if k not in _WAVE_PROGRAMS and k not in lowered:
-            lowered[k] = _apply_wave_jit.lower(params, index, *args, *static)
-    if lowered:
-        workers = min(len(lowered), os.cpu_count() or 1, 8)
-        with ThreadPoolExecutor(workers) as pool:
-            compiled = pool.map(lambda low: low.compile(), lowered.values())
-            _WAVE_PROGRAMS.update(zip(lowered, compiled))
+    missing = {k: call for k, call in zip(keys, calls)
+               if k not in _WAVE_PROGRAMS}
+    if missing:
+        with span("wave_compile", programs=len(missing)):
+            lowered = [_apply_wave_jit.lower(params, index, *args, *static)
+                       for args, static in missing.values()]
+            workers = min(len(lowered), os.cpu_count() or 1, 8)
+            with ThreadPoolExecutor(workers) as pool:
+                compiled = pool.map(lambda low: low.compile(), lowered)
+                _WAVE_PROGRAMS.update(zip(missing, compiled))
     return [_WAVE_PROGRAMS[k] for k in keys]
 
 

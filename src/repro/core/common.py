@@ -3,9 +3,14 @@
 Everything here is pure jnp, shape-static, and jit/vmap friendly. Distance
 kernels live in :mod:`~repro.core.metrics` (pluggable l2/ip/cosine spaces);
 the squared-L2 names are re-exported here for the pre-metric-registry call
-sites.
+sites. The one host-side helper is :func:`span`, the program's named spans
+in the profiler's trace.
 """
 from __future__ import annotations
+
+import contextlib
+import contextvars
+import gc
 
 import jax
 import jax.numpy as jnp
@@ -18,6 +23,53 @@ pairwise_sqdist = sqdist_pairwise
 
 INF = jnp.float32(jnp.inf)
 INVALID = jnp.int32(-1)
+
+
+# ---------------------------------------------------------------------------
+# host spans
+# ---------------------------------------------------------------------------
+
+#: metadata every span opened in this context carries (the engine's pump)
+_SPAN_TAGS: contextvars.ContextVar[dict] = contextvars.ContextVar(
+    "repro_span_tags", default={})
+
+
+def span(name: str, **meta):
+    """A host span ``repro.<name>`` in the profiler's own trace, on the
+    device trace's clock. ``meta`` (and any :func:`span_tags` in force)
+    arrive as event stats, not in the name. With no profiler session a span
+    costs about a microsecond, so spans are always on."""
+    return jax.profiler.TraceAnnotation("repro." + name,
+                                        **{**_SPAN_TAGS.get(), **meta})
+
+
+@contextlib.contextmanager
+def span_tags(**tags):
+    """Add ``tags`` to the metadata of every span opened inside."""
+    token = _SPAN_TAGS.set({**_SPAN_TAGS.get(), **tags})
+    try:
+        yield
+    finally:
+        _SPAN_TAGS.reset(token)
+
+
+_gc_open: list = []      # the span of the collection in progress, if any
+
+
+def _gc_span(phase: str, info: dict) -> None:
+    if phase == "start":
+        s = span("gc", gen=info["generation"])
+        s.__enter__()
+        _gc_open.append(s)
+    elif _gc_open:
+        _gc_open.pop().__exit__(None, None, None)
+
+
+def trace_gc() -> None:
+    """Mark each Python GC pause with a ``repro.gc`` span (process-wide,
+    installed once)."""
+    if _gc_span not in gc.callbacks:
+        gc.callbacks.append(_gc_span)
 
 
 def pow2_at_least(n: int) -> int:

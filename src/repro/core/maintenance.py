@@ -40,7 +40,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .common import INF, INVALID, pow2_at_least
+from .common import INF, INVALID, pow2_at_least, span
 from .index import HNSWIndex, HNSWParams, empty_index
 from .metrics import dist_point
 from .prune import alpha_rng_select
@@ -432,19 +432,23 @@ def run_maintenance(params: HNSWParams, index: HNSWIndex,
               "unreachable_def1": int(h.unreachable_def1)}
     ran = False
     if policy.should_consolidate(h):
-        index = consolidate_deletes(params, index)
+        with span("consolidate"):
+            index = consolidate_deletes(params, index)
         report["consolidated"] = True
         report["reclaimed"] = int(h.deleted)
         ran = True
     if ran or policy.should_repair(h):
         for _ in range(policy.repair_passes):
-            def1 = int(jnp.sum(indegree_unreachable(index)))
+            with span("recheck"):
+                def1 = int(jnp.sum(indegree_unreachable(index)))
             report["unreachable_def1"] = def1
             if def1 <= policy.unreachable:
                 break
-            index = repair_unreachable(params, index)
+            with span("repair"):
+                index = repair_unreachable(params, index)
             report["repair_passes"] += 1
         else:
-            report["unreachable_def1"] = int(
-                jnp.sum(indegree_unreachable(index)))
+            with span("recheck"):
+                report["unreachable_def1"] = int(
+                    jnp.sum(indegree_unreachable(index)))
     return index, report

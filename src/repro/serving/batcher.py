@@ -26,6 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.backup import batch_dual_search
+from repro.core.common import span
 from repro.core.index import HNSWParams
 from repro.core.metrics import get_metric, normalize_rows
 from repro.core.planner import (DEFAULT_PLANNER, MODES, PlannerConfig,
@@ -162,11 +163,16 @@ class MicroBatcher:
         if self.mode != "auto":
             return self.mode
         if self._stats_cache is None or self._stats_cache[0] != snapshot.epoch:
-            self._stats_cache = (snapshot.epoch, index_stats(snapshot.index))
+            with span("plan", epoch=snapshot.epoch):
+                t0 = time.perf_counter()
+                self._stats_cache = (snapshot.epoch,
+                                     index_stats(snapshot.index))
+                self.metrics.counter("plan_us").inc(
+                    round((time.perf_counter() - t0) * 1e6))
         return choose_tier(self._stats_cache[1], self.planner).tier
 
     def _default_search(self, snapshot: EpochSnapshot, Q: jnp.ndarray):
-        tier = self._plan_tier(snapshot)
+        tier = self._plan_tier(snapshot)     # cached by _dispatch
         self.metrics.counter(f"tier_{tier}_batches").inc()
         if tier == "exact":
             labels, _, dists = exact_scan(self.params, snapshot.index, Q,
@@ -189,31 +195,42 @@ class MicroBatcher:
         back to back — every ticket in the flush still sees the same epoch.
         """
         completed: list[QueryTicket] = []
-        while self._pending:
-            take = min(len(self._pending), self.max_batch)
-            batch = self._pending[:take]
-            del self._pending[:take]
+        if not self._pending:
+            return completed
+        with span("serve", queries=len(self._pending)):
+            while self._pending:
+                completed.extend(self._dispatch(snapshot))
+        return completed
 
-            b = bucket_size(take, self.max_batch)
-            Q = np.empty((b, batch[0].vector.shape[0]), np.float32)
-            for i, t in enumerate(batch):
-                Q[i] = t.vector
-            Q[take:] = batch[0].vector          # pad rows: discarded below
+    def _dispatch(self, snapshot: EpochSnapshot) -> list[QueryTicket]:
+        """Serve the oldest ``max_batch`` pending queries as one batch."""
+        take = min(len(self._pending), self.max_batch)
+        batch = self._pending[:take]
+        del self._pending[:take]
 
+        b = bucket_size(take, self.max_batch)
+        Q = np.empty((b, batch[0].vector.shape[0]), np.float32)
+        for i, t in enumerate(batch):
+            Q[i] = t.vector
+        Q[take:] = batch[0].vector          # pad rows: discarded below
+
+        # an injected search_fn routes by itself: no planner consult
+        tier = (self._plan_tier(snapshot)
+                if self._search_fn == self._default_search else self.mode)
+        with span("dispatch", rows=take, bucket=b, tier=tier):
             t0 = time.perf_counter()
             labels, dists = self._search_fn(snapshot, jnp.asarray(Q))
             labels = np.asarray(labels)
             dists = np.asarray(dists)
             dt = time.perf_counter() - t0
 
-            for i, t in enumerate(batch):
-                t._complete(labels[i], dists[i], snapshot.epoch)
-                self.metrics.histogram("query_latency_ms").observe(
-                    t.latency_s * 1e3)
-            completed.extend(batch)
-            self.metrics.counter("batches_dispatched").inc()
-            self.metrics.counter("queries_served").inc(take)
-            self.metrics.counter("pad_waste_rows").inc(b - take)
-            self.metrics.histogram("batch_latency_ms").observe(dt * 1e3)
-            self.metrics.histogram("batch_fill").observe(take / b)
-        return completed
+        for i, t in enumerate(batch):
+            t._complete(labels[i], dists[i], snapshot.epoch)
+        self.metrics.counter("query_wait_us").inc(
+            round(sum(t0 - t._submit_t for t in batch) * 1e6))
+        self.metrics.counter("batches_dispatched").inc()
+        self.metrics.counter("queries_served").inc(take)
+        self.metrics.counter("pad_waste_rows").inc(b - take)
+        self.metrics.histogram("batch_latency_ms").observe(dt * 1e3)
+        self.metrics.histogram("batch_fill").observe(take / b)
+        return batch

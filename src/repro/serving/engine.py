@@ -44,12 +44,12 @@ only for now.
 from __future__ import annotations
 
 import dataclasses
-import time
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.common import span, span_tags, trace_gc
 from repro.core.index import HNSWIndex, HNSWParams, empty_index
 from repro.core.maintenance import (MaintenancePolicy, index_health,
                                     run_maintenance)
@@ -108,6 +108,7 @@ class ServingEngine:
         self._dirty_since_consult = True   # writes since the last consult
         self.metrics = metrics or MetricsRegistry()
         self.dim = int(index.vectors.shape[-1])
+        trace_gc()
 
         sharded = mesh is not None
         use_backup = tau > 0 and backup_capacity > 0
@@ -203,10 +204,18 @@ class ServingEngine:
 
     # -- the event loop -----------------------------------------------------
     def pump(self, max_updates: int | None = None) -> PumpStats:
-        """One deterministic serve/maintain/publish step."""
-        t0 = time.perf_counter()
-        snap = self.store.current()
+        """One deterministic serve/maintain/publish step.
 
+        Each step runs inside a ``repro.*`` host span (:func:`span`), all
+        tagged ``pump=<n>`` with this pump's number (the ``pumps`` counter
+        once it returns)."""
+        snap = self.store.current()
+        n = self.metrics.counter("pumps").value + 1
+        with span_tags(pump=n), span("pump", epoch=snap.epoch):
+            return self._pump(snap, max_updates)
+
+    def _pump(self, snap: EpochSnapshot, max_updates: int | None
+              ) -> PumpStats:
         served = self.batcher.flush(snap)
 
         new_index, applied = self.scheduler.drain(self.store.working_index(),
@@ -225,31 +234,33 @@ class ServingEngine:
             self._last_health = None
         maintained = self._maybe_maintain()
 
-        out = self.store.publish()
+        with span("publish"):
+            out = self.store.publish()
+            if self.track_unreachable and out.epoch != snap.epoch:
+                self._gauge_unreachable(out.index)
 
         self.metrics.counter("pumps").inc()
         self.metrics.set_gauge("epoch", out.epoch)
         self.metrics.set_gauge("waves_per_pump", waves)
         self.metrics.set_gauge("update_lag_ops", self.scheduler.backlog)
-        self.metrics.histogram("pump_ms").observe(
-            (time.perf_counter() - t0) * 1e3)
-        if self.track_unreachable and out.epoch != snap.epoch:
-            if self.mesh is not None:
-                u_ind, u_bfs = self._sharded_count_unreachable(out.index)
-            elif self._last_health is not None:
-                # the maintenance consult already swept this exact index —
-                # don't run the O(L*N*M0) reachability fix-point twice
-                u_ind = int(self._last_health.unreachable_def1)
-                u_bfs = int(self._last_health.unreachable_bfs)
-            else:
-                u_ind, u_bfs = count_unreachable(out.index)
-            self.metrics.set_gauge("unreachable_indegree", int(u_ind))
-            self.metrics.set_gauge("unreachable_bfs", int(u_bfs))
-            self.metrics.histogram("unreachable_per_epoch").observe(int(u_ind))
         return PumpStats(epoch=out.epoch, queries_served=len(served),
                          updates_applied=applied, backup_rebuilt=rebuilt,
                          update_backlog=self.scheduler.backlog,
                          maintenance_ran=maintained, waves_per_pump=waves)
+
+    def _gauge_unreachable(self, index: HNSWIndex) -> None:
+        if self.mesh is not None:
+            u_ind, u_bfs = self._sharded_count_unreachable(index)
+        elif self._last_health is not None:
+            # the maintenance consult already swept this exact index —
+            # don't run the O(L*N*M0) reachability fix-point twice
+            u_ind = int(self._last_health.unreachable_def1)
+            u_bfs = int(self._last_health.unreachable_bfs)
+        else:
+            u_ind, u_bfs = count_unreachable(index)
+        self.metrics.set_gauge("unreachable_indegree", int(u_ind))
+        self.metrics.set_gauge("unreachable_bfs", int(u_bfs))
+        self.metrics.histogram("unreachable_per_epoch").observe(int(u_ind))
 
     def _sharded_count_unreachable(self, stacked: HNSWIndex):
         """Per-shard reachability sweeps summed into the global gauges.
@@ -285,8 +296,13 @@ class ServingEngine:
             # reachability sweep (``_last_health`` stays valid too)
             return False
         self._pumps_since_maintenance = 0
-        t0 = time.perf_counter()
-        h = index_health(self.store.working_index())
+        with span("maintain"):
+            return self._maintain()
+
+    def _maintain(self) -> bool:
+        with span("health"):
+            # one device-to-host read of every field the policy consults
+            h = jax.device_get(index_health(self.store.working_index()))
         new_index, report = run_maintenance(
             self.params, self.store.working_index(), self.maintenance,
             health=h)
@@ -310,8 +326,6 @@ class ServingEngine:
             report["repair_passes"])
         self.metrics.set_gauge("maintenance_unreachable_def1",
                                report["unreachable_def1"])
-        self.metrics.histogram("maintenance_ms").observe(
-            (time.perf_counter() - t0) * 1e3)
         return True
 
     def drain_all(self, max_pumps: int = 1_000) -> list[PumpStats]:

@@ -35,7 +35,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.backup import rebuild_backup
-from repro.core.batch_update import apply_plan, compile_tape
+from repro.core.batch_update import (apply_plan, compile_tape,
+                                     compiled_wave_programs)
+from repro.core.common import span
 from repro.core.index import HNSWIndex, HNSWParams
 from repro.core.metrics import get_metric, normalize_rows
 from repro.core.strategies import get_executor, get_strategy
@@ -154,12 +156,19 @@ class UpdateScheduler:
                 and get_strategy(self.variant).repair_fn is None)
         if wave:
             def fn(index, ops, labels, X):
-                plan = compile_tape(ops, labels, X, built=int(index.count))
+                with span("tape") as s:
+                    plan = compile_tape(ops, labels, X,
+                                        built=int(index.count))
+                    s.set_metadata(waves=plan.num_waves)
                 self.last_drain_waves = plan.num_waves + (
                     1 if plan.num_deletes else 0)
                 if plan.deduped:
                     self.metrics.counter("updates_deduped").inc(plan.deduped)
-                return apply_plan(self.params, index, plan, self.variant)
+                n0 = compiled_wave_programs()
+                index = apply_plan(self.params, index, plan, self.variant)
+                self.metrics.counter("wave_programs_compiled").inc(
+                    compiled_wave_programs() - n0)
+                return index
             return fn
         jfn = jax.jit(apply_update_batch_sequential,
                       static_argnames=("params", "variant"))
@@ -201,25 +210,26 @@ class UpdateScheduler:
         take = min(len(self._queue), limit)
         if take == 0:
             return index, 0
-        batch = [self._queue.popleft() for _ in range(take)]
+        with span("drain", ops=take):
+            now = time.perf_counter()
+            batch = [self._queue.popleft() for _ in range(take)]
 
-        b = bucket_size(take, self.max_ops_per_drain)
-        ops = np.full((b,), OP_NOP, np.int32)
-        labels = np.full((b,), -1, np.int32)
-        X = np.zeros((b, self.dim), np.float32)
-        now = time.perf_counter()
-        for i, op in enumerate(batch):
-            ops[i] = op.opcode
-            labels[i] = op.label
-            if op.vector is not None:
-                X[i] = op.vector
-            self.metrics.histogram("update_queue_wait_ms").observe(
-                (now - op.enqueued_t) * 1e3)
+            b = bucket_size(take, self.max_ops_per_drain)
+            ops = np.full((b,), OP_NOP, np.int32)
+            labels = np.full((b,), -1, np.int32)
+            X = np.zeros((b, self.dim), np.float32)
+            for i, op in enumerate(batch):
+                ops[i] = op.opcode
+                labels[i] = op.label
+                if op.vector is not None:
+                    X[i] = op.vector
 
-        t0 = time.perf_counter()
-        index = self._apply_fn(index, ops, labels, X)
-        self.metrics.histogram("drain_latency_ms").observe(
-            (time.perf_counter() - t0) * 1e3)
+            t0 = time.perf_counter()
+            index = self._apply_fn(index, ops, labels, X)
+            self.metrics.histogram("drain_latency_ms").observe(
+                (time.perf_counter() - t0) * 1e3)
+        self.metrics.counter("update_wait_us").inc(
+            round(sum(now - op.enqueued_t for op in batch) * 1e6))
         self._ru_ops += sum(1 for op in batch if op.kind != "delete")
         self.metrics.counter("updates_applied").inc(take)
         self.metrics.counter("update_drains").inc()
@@ -243,10 +253,11 @@ class UpdateScheduler:
         if not self.rebuild_due:
             return None
         t0 = time.perf_counter()
-        backup = rebuild_backup(self.backup_params, index,
-                                self.backup_capacity,
-                                jnp.uint32(self._rebuilds + 1))
-        backup.vectors.block_until_ready()
+        with span("backup"):
+            backup = rebuild_backup(self.backup_params, index,
+                                    self.backup_capacity,
+                                    jnp.uint32(self._rebuilds + 1))
+            backup.vectors.block_until_ready()
         # one drain can cross several tau thresholds — catch the counter up
         # so idle pumps don't rebuild the identical index again
         self._rebuilds = self._ru_ops // self.tau
