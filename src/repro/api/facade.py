@@ -57,13 +57,14 @@ from repro.core.index import (HNSWIndex, HNSWParams, empty_index,
                               resize_index)
 from repro.core.common import pow2_at_least as _pow2_at_least
 from repro.core.maintenance import (IndexHealth, MaintenancePolicy,
-                                    consolidate_deletes, count_unreachable,
-                                    index_health, rebuild_index,
+                                    consolidate_deletes, index_health,
+                                    rebuild_index,
                                     repair_unreachable as _repair_unreachable,
                                     run_maintenance)
 from repro.core.metrics import get_metric, normalize_rows
 from repro.core.planner import (DEFAULT_PLANNER, PlanDecision, PlannerConfig,
                                 choose_tier, index_stats, plan_and_search)
+from repro.core.reach import indegree_unreachable
 from repro.core.strategies import get_strategy
 from repro.core.update import (OP_DELETE, OP_INSERT, OP_REPLACE, OP_NOP,
                                apply_update_batch_jit, num_deleted)
@@ -361,11 +362,10 @@ class VectorIndex:
         ``max_passes`` is exhausted. Returns the remaining Definition-1
         count (0 on success)."""
         for _ in range(max_passes):
-            def1, _bfs = count_unreachable(self._index)
-            if int(def1) == 0:
+            if int(jnp.sum(indegree_unreachable(self._index))) == 0:
                 return 0
             self._index = _repair_unreachable(self.params, self._index)
-        return int(count_unreachable(self._index)[0])
+        return int(jnp.sum(indegree_unreachable(self._index)))
 
     # -- reads --------------------------------------------------------------
 

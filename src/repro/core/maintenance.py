@@ -17,11 +17,13 @@ the full blocking rebuild that used to be the only reclamation path:
     point (Definition-1 ∪ BFS) through the layer-inheriting reinsert path,
     then force an in-edge into every remaining orphan without taking
     another point's last one, driving the Definition-1 count to zero.
+    Inside a maintenance consult it takes the health sweep's mask instead
+    of sweeping again, and leaves the Definition-1 count of its output.
   * :func:`index_health` — a jit-able :class:`IndexHealth` report (live /
-    deleted / unreachable counts, in-degree histogram) that
-    :class:`MaintenancePolicy` consumes to decide *when* the passes run —
-    between serving ``pump()`` ticks off-snapshot, or transparently behind
-    the facade's mutation calls.
+    deleted / unreachable counts, in-degree histogram, the repair mask)
+    that :class:`MaintenancePolicy` consumes to decide *when* the passes
+    run — between serving ``pump()`` ticks off-snapshot, or transparently
+    behind the facade's mutation calls.
   * :func:`rebuild_index` — the full rebuild over live points, kept as the
     escape hatch (``VectorIndex.compact()`` routes here).
 
@@ -33,6 +35,7 @@ from an occasional :func:`rebuild_index`. See docs/MAINTENANCE.md.
 """
 from __future__ import annotations
 
+import contextvars
 import dataclasses
 from functools import partial
 
@@ -44,8 +47,8 @@ from .common import INF, INVALID, pow2_at_least, span
 from .index import HNSWIndex, HNSWParams, empty_index
 from .metrics import dist_point
 from .prune import alpha_rng_select
-from .reach import bfs_unreachable, count_unreachable, definition1, \
-    indegree, indegree_unreachable
+from .reach import bfs_unreachable, definition1, indegree, \
+    indegree_unreachable
 
 
 # ---------------------------------------------------------------------------
@@ -63,7 +66,7 @@ HIST_SPLITS = (1, 2, 4, 8, 16, 32, 64)
     jax.tree_util.register_dataclass,
     data_fields=["capacity", "allocated", "live", "deleted",
                  "unreachable_def1", "unreachable_bfs", "max_layer",
-                 "indegree_hist"],
+                 "indegree_hist", "repair_mask"],
     meta_fields=[],
 )
 @dataclasses.dataclass
@@ -77,6 +80,14 @@ class IndexHealth:
     unreachable_bfs: jax.Array   # i32[] BFS-unreachable count
     max_layer: jax.Array         # i32[] current top layer (-1 = empty)
     indegree_hist: jax.Array     # i32[len(HIST_SPLITS)+1] live in-degree bins
+    repair_mask: jax.Array       # bool[N] live points that fail Definition 1
+                                 # or that the BFS does not reach
+
+    def fetch(self) -> IndexHealth:
+        """The report with every count read to the host in one transfer;
+        the repair mask stays on the device."""
+        counts = jax.device_get(dataclasses.replace(self, repair_mask=None))
+        return dataclasses.replace(counts, repair_mask=self.repair_mask)
 
     @property
     def deleted_frac(self) -> float:
@@ -111,13 +122,15 @@ def index_health(index: HNSWIndex) -> IndexHealth:
 
     A handful of O(N) reductions plus the BFS reachability fix-point —
     cheap next to one update drain, which is why the maintenance policy can
-    afford to consult it every cycle.
+    afford to consult it every cycle. The union of both criteria is the
+    mask :func:`repair_unreachable` re-links, so a pass on the same index
+    need not sweep again.
     """
     alloc = index.levels >= 0
     live = alloc & ~index.deleted
     deg = indegree(index)
-    u_def1 = jnp.sum(definition1(index, deg))
-    u_bfs = jnp.sum(bfs_unreachable(index))
+    def1 = definition1(index, deg)
+    bfs = bfs_unreachable(index)
     nbins = len(HIST_SPLITS) + 1
     b = jnp.searchsorted(jnp.asarray(HIST_SPLITS, jnp.int32), deg,
                          side="right")
@@ -128,10 +141,11 @@ def index_health(index: HNSWIndex) -> IndexHealth:
         allocated=jnp.sum(alloc).astype(jnp.int32),
         live=jnp.sum(live).astype(jnp.int32),
         deleted=jnp.sum(alloc & index.deleted).astype(jnp.int32),
-        unreachable_def1=u_def1.astype(jnp.int32),
-        unreachable_bfs=u_bfs.astype(jnp.int32),
+        unreachable_def1=jnp.sum(def1, dtype=jnp.int32),
+        unreachable_bfs=jnp.sum(bfs, dtype=jnp.int32),
         max_layer=index.max_layer.astype(jnp.int32),
         indegree_hist=hist,
+        repair_mask=def1 | bfs,
     )
 
 
@@ -263,7 +277,8 @@ def consolidate_deletes(params: HNSWParams, index: HNSWIndex) -> HNSWIndex:
 # unreachable-point repair
 # ---------------------------------------------------------------------------
 
-def _force_in_edges(params: HNSWParams, index: HNSWIndex) -> HNSWIndex:
+def _force_in_edges(params: HNSWParams, index: HNSWIndex
+                    ) -> tuple[HNSWIndex, jax.Array]:
     """Connectivity backstop: give every Definition-1 orphan an in-edge.
 
     Re-linking point A can cost point B its last in-edge (A's reverse
@@ -273,7 +288,7 @@ def _force_in_edges(params: HNSWParams, index: HNSWIndex) -> HNSWIndex:
     a free slot or holds a point with another in-edge to spare (the
     farthest such point is evicted) — the keep-connected override hnswlib
     applies, with in-degrees kept up to date so that no link taken here
-    orphans anyone.
+    orphans anyone. Returns the index and its :func:`indegree`.
     """
     L, N, M0 = index.neighbors.shape
     deg = indegree(index)
@@ -303,35 +318,20 @@ def _force_in_edges(params: HNSWParams, index: HNSWIndex) -> HNSWIndex:
             -(ok & (victim >= 0)).astype(jnp.int32))
         return nbrs, deg
 
-    nbrs, _ = jax.lax.fori_loop(0, jnp.sum(mask, dtype=jnp.int32), body,
-                                (index.neighbors, deg))
-    return dataclasses.replace(index, neighbors=nbrs)
+    nbrs, deg = jax.lax.fori_loop(0, jnp.sum(mask, dtype=jnp.int32), body,
+                                  (index.neighbors, deg))
+    return dataclasses.replace(index, neighbors=nbrs), deg
 
 
-@partial(jax.jit, static_argnames=("params",))
-def repair_unreachable(params: HNSWParams, index: HNSWIndex) -> HNSWIndex:
-    """Batch re-link every unreachable live point back into the graph.
-
-    Sweeps the union of the paper's Definition-1 criterion
-    (:func:`~repro.core.reach.indegree_unreachable`) and BFS
-    unreachability, then re-links each point through the layer-inheriting
-    reinsert path (paper Algorithm 3: greedy descent above its level, beam
-    search + alpha-RNG select + reverse edges at its levels), followed by
-    the :func:`_force_in_edges` backstop for the Definition-1 orphans the
-    re-links left or made. One compiled program; the loop bounds are the
-    (traced) unreachable counts, so a healthy index pays only the
-    detection sweeps.
-
-    The backstop leaves a Definition-1 orphan only when none of its
-    layer-0 out-neighbours can take it without orphaning another point;
-    :func:`run_maintenance` / ``VectorIndex.repair_unreachable`` re-check
-    and run further passes for that case.
-    """
+def _repair(params: HNSWParams, index: HNSWIndex, mask: jax.Array
+            ) -> tuple[HNSWIndex, jax.Array]:
+    """Re-link every point of ``mask``, then the backstop: the repaired
+    index and its Definition-1 count, read off the in-degree the backstop
+    keeps current."""
     # local import: update.py imports nothing from this module, so the
     # dependency stays one-directional at runtime (both live in core)
     from .update import _update_reinsert
 
-    mask = indegree_unreachable(index) | bfs_unreachable(index)
     N = index.capacity
     order = jnp.argsort(jnp.where(mask, jnp.arange(N), N))   # unreachable first
     n_u = jnp.sum(mask).astype(jnp.int32)
@@ -340,7 +340,66 @@ def repair_unreachable(params: HNSWParams, index: HNSWIndex) -> HNSWIndex:
         pid = order[i]
         return _update_reinsert(params, ix, ix.vectors[pid], pid, params.alpha)
 
-    return _force_in_edges(params, jax.lax.fori_loop(0, n_u, body, index))
+    out, deg = _force_in_edges(params, jax.lax.fori_loop(0, n_u, body, index))
+    return out, jnp.sum(definition1(out, deg), dtype=jnp.int32)
+
+
+# the trace names a program after its function, and the maintenance layer's
+# device time is read under the public name
+_repair.__name__ = "repair_unreachable"
+_repair = jax.jit(_repair, static_argnames=("params",))
+
+
+@dataclasses.dataclass
+class _Known:
+    """What a maintenance consult knows of ``index`` without sweeping it:
+    its Definition-1 count and, until a pass changes it, its repair mask;
+    and how many passes took such a mask."""
+    index: HNSWIndex
+    def1: int | jax.Array
+    mask: jax.Array | None
+    mask_reused: int = 0
+
+
+# set by run_maintenance around its repair passes; repair_unreachable keeps
+# its public signature and reads the consult's sweep from here
+_consult: contextvars.ContextVar[_Known | None] = contextvars.ContextVar(
+    "repro_maintenance_consult", default=None)
+
+
+def repair_unreachable(params: HNSWParams, index: HNSWIndex) -> HNSWIndex:
+    """Batch re-link every unreachable live point back into the graph.
+
+    Takes the union of the paper's Definition-1 criterion
+    (:func:`~repro.core.reach.indegree_unreachable`) and BFS
+    unreachability, :attr:`IndexHealth.repair_mask`, then re-links each
+    point through the layer-inheriting reinsert path (paper Algorithm 3:
+    greedy descent above its level, beam search + alpha-RNG select +
+    reverse edges at its levels), followed by the :func:`_force_in_edges`
+    backstop for the Definition-1 orphans the re-links left or made. One
+    compiled program; the loop bounds are the (traced) unreachable counts.
+
+    Inside :func:`run_maintenance` a pass on the index the consult swept
+    takes that sweep's mask, and each pass leaves the Definition-1 count of
+    its output for the check after it: neither sweeps again. Anywhere else
+    the mask comes from an :func:`index_health` sweep of ``index``.
+
+    The backstop leaves a Definition-1 orphan only when none of its
+    layer-0 out-neighbours can take it without orphaning another point;
+    :func:`run_maintenance` / ``VectorIndex.repair_unreachable`` re-check
+    and run further passes for that case.
+    """
+    known = _consult.get()
+    if known is not None and known.index is index \
+            and known.mask is not None:
+        mask = known.mask
+        known.mask_reused += 1
+    else:
+        mask = index_health(index).repair_mask
+    out, def1 = _repair(params, index, mask)
+    if known is not None:
+        known.index, known.def1, known.mask = out, def1, None
+    return out
 
 
 # ---------------------------------------------------------------------------
@@ -422,33 +481,51 @@ def run_maintenance(params: HNSWParams, index: HNSWIndex,
 
     Returns ``(index, report)`` where ``report`` records what ran:
     ``{"consolidated": bool, "reclaimed": int, "repair_passes": int,
-    "unreachable_def1": int}``. Repair follows consolidation because
-    clearing deleted slots can orphan points whose in-edges ran through
-    them; the repair loop re-checks the Definition-1 count between sweeps
-    and stops at ``policy.repair_passes``.
+    "unreachable_def1": int, "mask_reused": int, "recheck_sweeps": int}``.
+    Repair follows consolidation because clearing deleted slots can orphan
+    points whose in-edges ran through them; the repair loop re-checks the
+    Definition-1 count between sweeps and stops at ``policy.repair_passes``.
+
+    ``health`` (default: swept here) must describe ``index``. Its sweep
+    stands until the index changes: its count is the check before the
+    first pass, and that pass takes its mask (``mask_reused``). A
+    consolidation changes the index, so one :func:`index_health` sweep
+    then serves as both (``recheck_sweeps``). The check after each pass
+    reads the count the pass left; a second pass sweeps for its own mask.
     """
-    h = health if health is not None else index_health(index)
+    h = health if health is not None else index_health(index).fetch()
     report = {"consolidated": False, "reclaimed": 0, "repair_passes": 0,
-              "unreachable_def1": int(h.unreachable_def1)}
-    ran = False
+              "unreachable_def1": int(h.unreachable_def1),
+              "mask_reused": 0, "recheck_sweeps": 0}
     if policy.should_consolidate(h):
         with span("consolidate"):
             index = consolidate_deletes(params, index)
         report["consolidated"] = True
         report["reclaimed"] = int(h.deleted)
-        ran = True
-    if ran or policy.should_repair(h):
+        with span("recheck"):
+            h = index_health(index).fetch()
+        report["recheck_sweeps"] += 1
+    elif not policy.should_repair(h):
+        return index, report
+    known = _Known(index, int(h.unreachable_def1), h.repair_mask)
+    token = _consult.set(known)
+    try:
+        def1 = known.def1
         for _ in range(policy.repair_passes):
-            with span("recheck"):
-                def1 = int(jnp.sum(indegree_unreachable(index)))
-            report["unreachable_def1"] = def1
             if def1 <= policy.unreachable:
                 break
             with span("repair"):
                 index = repair_unreachable(params, index)
             report["repair_passes"] += 1
-        else:
             with span("recheck"):
-                report["unreachable_def1"] = int(
-                    jnp.sum(indegree_unreachable(index)))
+                # a repair that bypassed the consult left no count
+                if known.index is not index:
+                    known.index, known.mask = index, None
+                    known.def1 = jnp.sum(indegree_unreachable(index))
+                    report["recheck_sweeps"] += 1
+                def1 = int(known.def1)
+    finally:
+        _consult.reset(token)
+    report["unreachable_def1"] = def1
+    report["mask_reused"] = known.mask_reused
     return index, report

@@ -301,8 +301,9 @@ class ServingEngine:
 
     def _maintain(self) -> bool:
         with span("health"):
-            # one device-to-host read of every field the policy consults
-            h = jax.device_get(index_health(self.store.working_index()))
+            # one device-to-host read of every count the policy consults;
+            # the repair mask stays on the device for the first pass
+            h = index_health(self.store.working_index()).fetch()
         new_index, report = run_maintenance(
             self.params, self.store.working_index(), self.maintenance,
             health=h)
@@ -324,6 +325,10 @@ class ServingEngine:
                 report["reclaimed"])
         self.metrics.counter("maintenance_repair_passes").inc(
             report["repair_passes"])
+        self.metrics.counter("maintenance_mask_reused").inc(
+            report["mask_reused"])
+        self.metrics.counter("maintenance_recheck_sweeps").inc(
+            report["recheck_sweeps"])
         self.metrics.set_gauge("maintenance_unreachable_def1",
                                report["unreachable_def1"])
         return True

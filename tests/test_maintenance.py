@@ -339,14 +339,10 @@ def test_interleaved_update_consolidate_never_loses_live_labels():
     run()
 
 
-def test_backstop_keeps_every_last_in_edge():
-    """The orphan backstop links an orphan from its nearest out-neighbour
-    and evicts the farthest entry that has another in-edge, never a
-    point's last one: slot 4 is the farthest entry of owner 0's full row
-    but has no other in-edge, so slot 3 is evicted instead."""
+def _backstop_graph():
+    """Slot 6 is the one orphan; slot 4 is the farthest entry of owner 0's
+    full row but has no other in-edge."""
     from repro.core.index import HNSWIndex, HNSWParams
-    from repro.core.maintenance import _force_in_edges
-    from repro.core.reach import indegree_unreachable
     params = HNSWParams(M=4, M0=4, num_layers=1)
     rows = {0: [1, 2, 3, 4], 4: [5], 5: [1, 2, 3], 6: [0]}
     nbrs = np.full((1, 8, 4), -1, np.int32)
@@ -360,8 +356,161 @@ def test_backstop_keeps_every_last_in_edge():
         neighbors=jnp.asarray(nbrs), deleted=jnp.zeros(8, bool),
         entry=jnp.int32(0), max_layer=jnp.int32(0), count=jnp.int32(7),
         rng=jnp.zeros(2, jnp.uint32))
+    return params, idx
+
+
+def test_backstop_keeps_every_last_in_edge():
+    """The orphan backstop links an orphan from its nearest out-neighbour
+    and evicts the farthest entry that has another in-edge, never a
+    point's last one: slot 4 is the farthest entry of owner 0's full row
+    but has no other in-edge, so slot 3 is evicted instead. The in-degree
+    it hands back is that of its output."""
+    from repro.core.maintenance import _force_in_edges
+    from repro.core.reach import indegree, indegree_unreachable
+    params, idx = _backstop_graph()
     assert np.nonzero(np.asarray(indegree_unreachable(idx)))[0].tolist() \
         == [6]
-    out = _force_in_edges(params, idx)
+    out, deg = _force_in_edges(params, idx)
     assert np.asarray(out.neighbors)[0, 0].tolist() == [1, 2, 6, 4]
     assert not np.asarray(indegree_unreachable(out)).any()
+    assert np.array_equal(deg, indegree(out))
+
+
+# ---------------------------------------------------------------------------
+# one reachability sweep per consult
+# ---------------------------------------------------------------------------
+
+#: (seed, M, M0, points stripped of their in-edges): with M0 = 4 one pass
+#: repairs them all; with M = M0 = 2 the backstop leaves orphans behind
+ORPHANED = {"repaired_in_one_pass": (1, 2, 4, 24),
+            "backstop_leaves_orphans": (0, 2, 2, 24)}
+
+
+def _orphaned(case):
+    seed, M, M0, n_orphans = ORPHANED[case]
+    vi = api.create(space="l2", dim=4, capacity=64, M=M, M0=M0,
+                    ef_construction=8, ef_search=8)
+    vi.add_items(clustered_vectors(64, 4, seed=seed))
+    _orphan(vi, n_orphans)
+    return vi.params, vi.index
+
+
+@pytest.mark.parametrize("case", ["backstop_graph", *ORPHANED])
+def test_repair_from_health_mask_equals_its_own_sweep(case):
+    """The mask health hands over is the union the repair used to sweep for
+    itself; re-linking from it gives the same index, and the count the
+    pass returns is the Definition-1 count of its output."""
+    from repro.core.maintenance import _repair, repair_unreachable
+    from repro.core.reach import bfs_unreachable, indegree_unreachable
+    params, idx = _backstop_graph() if case == "backstop_graph" \
+        else _orphaned(case)
+    h = index_health(idx)
+    assert int(h.unreachable_def1) > 0
+    swept = indegree_unreachable(idx) | bfs_unreachable(idx)
+    assert jnp.array_equal(h.repair_mask, swept)
+    out, left = _repair(params, idx, h.repair_mask)
+    _tree_equal(out, _repair(params, idx, swept)[0])
+    _tree_equal(out, repair_unreachable(params, idx))
+    assert int(left) == int(indegree_unreachable(out).sum())
+    assert (int(left) > 0) == (case == "backstop_leaves_orphans")
+
+
+def _old_consult(params, idx, policy):
+    """The consult as it ran before the sweep was carried forward: a fresh
+    Definition-1 sweep before and after every pass."""
+    from repro.core.maintenance import repair_unreachable
+    from repro.core.reach import indegree_unreachable
+    passes, def1 = 0, int(indegree_unreachable(idx).sum())
+    while passes < policy.repair_passes and def1 > policy.unreachable:
+        idx = repair_unreachable(params, idx)
+        passes += 1
+        def1 = int(indegree_unreachable(idx).sum())
+    return idx, passes, def1
+
+
+def _count_sweeps(monkeypatch):
+    """Count the eager sweeps the maintenance module dispatches, by name (a
+    call traced inside a program is not one)."""
+    from repro.core import maintenance
+    calls = []
+    for name in ("index_health", "indegree_unreachable"):
+        def counted(index, _sweep=getattr(maintenance, name), _name=name):
+            if not isinstance(index.neighbors, jax.core.Tracer):
+                calls.append(_name)
+            return _sweep(index)
+        monkeypatch.setattr(maintenance, name, counted)
+    return calls
+
+
+@pytest.mark.parametrize("case", sorted(ORPHANED))
+def test_run_maintenance_reuses_the_consults_mask(case, monkeypatch):
+    params, idx = _orphaned(case)
+    policy = MaintenancePolicy()
+    h = index_health(idx).fetch()
+    old, passes, def1 = _old_consult(params, idx, policy)
+    sweeps = _count_sweeps(monkeypatch)
+    new, report = run_maintenance(params, idx, policy, h)
+    assert report["mask_reused"] == 1 and report["recheck_sweeps"] == 0
+    # only a pass after the first sweeps, for its own mask
+    assert sweeps == ["index_health"] * (passes - 1)
+    assert (report["repair_passes"], report["unreachable_def1"]) \
+        == (passes, def1)
+    assert not report["consolidated"] and passes >= 1
+    _tree_equal(new, old)
+
+
+def test_run_maintenance_after_consolidation_sweeps_again(monkeypatch):
+    """A consolidation changes the index: one health sweep of the result is
+    the check before the first pass and that pass's mask; the consult's own
+    mask is not used."""
+    params, idx = _orphaned("repaired_in_one_pass")
+    policy = MaintenancePolicy(deleted_frac=0.1, min_deleted=8)
+    live = np.nonzero(np.asarray((idx.levels >= 0) & ~idx.deleted))[0]
+    idx = dataclasses.replace(idx, deleted=idx.deleted.at[live[-16:]].set(
+        True))
+    h = index_health(idx).fetch()
+    consolidated = consolidate_deletes(params, idx)
+    assert not jnp.array_equal(h.repair_mask,
+                               index_health(consolidated).repair_mask)
+    old, passes, def1 = _old_consult(params, consolidated, policy)
+    sweeps = _count_sweeps(monkeypatch)
+    out, report = run_maintenance(params, idx, policy, h)
+    assert report["consolidated"] and report["repair_passes"] == passes >= 1
+    assert report["recheck_sweeps"] == 1 and report["mask_reused"] == 1
+    assert sweeps == ["index_health"] * passes
+    assert report["unreachable_def1"] == def1 \
+        == int(count_unreachable(out)[0]) == 0
+    _tree_equal(out, old)
+
+
+def test_run_maintenance_repairs_through_repair_unreachable(monkeypatch):
+    """Every pass goes through ``repair_unreachable``: with it a no-op the
+    consult changes nothing and reports the orphans it left, without a
+    sweep of its own."""
+    from repro.core import maintenance
+    params, idx = _orphaned("repaired_in_one_pass")
+    h = index_health(idx).fetch()
+    monkeypatch.setattr(maintenance, "repair_unreachable",
+                        lambda params, index: index)
+    sweeps = _count_sweeps(monkeypatch)
+    out, report = run_maintenance(params, idx, MaintenancePolicy(), h)
+    assert out is idx and not sweeps
+    assert report["repair_passes"] == MaintenancePolicy().repair_passes
+    assert report["unreachable_def1"] == int(h.unreachable_def1) > 0
+    assert report["mask_reused"] == report["recheck_sweeps"] == 0
+
+
+def test_engine_counts_reused_masks_and_recheck_sweeps():
+    n, dim = 192, 8
+    X = clustered_vectors(n + 4, dim, seed=13)
+    vi = api.create(space="l2", dim=dim, capacity=256)
+    vi.add_items(X[:n])
+    _orphan(vi, 3)
+    eng = vi.serve(k=3, maintenance=MaintenancePolicy())
+    eng.insert(X[n], n)
+    assert eng.pump().maintenance_ran
+    c = eng.stats()["counters"]
+    assert c["maintenance_mask_reused"] == 1
+    assert c["maintenance_repair_passes"] >= 1
+    assert c["maintenance_recheck_sweeps"] == 0
+    assert eng.stats()["gauges"]["maintenance_unreachable_def1"] == 0

@@ -107,3 +107,19 @@ def test_wave_beam_tier_compiles(one_chip, index):
 def test_consolidate_deletes_compiles(index):
     """Consolidation re-prunes in row blocks, not all N pools at once."""
     _fits(consolidate_deletes.lower(PARAMS, index).compile())
+
+
+@pytest.mark.parametrize("program", ["index_health", "repair_unreachable"])
+def test_maintenance_consult_compiles(one_chip, index, program):
+    """The health sweep, and the repair pass that takes its mask: one
+    program each, under the names the maintenance layer's device time is
+    read by."""
+    from repro.core.maintenance import _repair, index_health
+    if program == "index_health":
+        lowered = index_health.lower(index)
+    else:
+        lowered = _repair.lower(PARAMS, index,
+                                _spec((N,), jnp.bool_, one_chip))
+    compiled = lowered.compile()
+    assert compiled.as_text().startswith(f"HloModule jit_{program}")
+    _fits(compiled)

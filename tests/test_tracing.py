@@ -139,7 +139,12 @@ def test_every_step_has_its_span_inside_its_pump(traced):
     assert ran["repair"] == c["maintenance_repair_passes"] >= 1
     assert ran["consolidate"] == c["maintenance_consolidations"] >= 1
     assert ran["maintain"] == ran["health"] >= 2
-    assert ran["recheck"] >= ran["repair"] + 1
+    # one check after each pass, plus one sweep before the first pass after
+    # a consolidation; before a first pass on the consult's own index the
+    # health sweep's count is the check
+    assert c["maintenance_recheck_sweeps"] == ran["consolidate"]
+    assert c["maintenance_mask_reused"] >= 1
+    assert ran["recheck"] == ran["repair"] + ran["consolidate"]
     assert ran["wave_compile"] >= 1 and traced["waves_compiled"] >= 1
 
 
